@@ -65,6 +65,8 @@ def main() -> None:
                     help="JSON report path (with --smoke)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (approx_backward, autotune_sweep, batched_solve,
                             bilevel_hypergrad, dictionary_learning,
                             distillation, fwd_vs_rev_hypergrad,

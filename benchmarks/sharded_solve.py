@@ -63,7 +63,6 @@ def _single_device_grad(X, y):
 
 
 def _sharded_grad(mesh, X, y):
-    from jax.experimental.shard_map import shard_map
     sharding = SolveSharding(mesh, P("data", None), batch_ndim=1,
                              theta_specs=(P("data"), P("data", None, None),
                                           P("data", None)))
@@ -71,11 +70,11 @@ def _sharded_grad(mesh, X, y):
                             sharding=sharding)
 
     def fwd(init, theta, X, y):
-        return shard_map(_local_solver, mesh=mesh,
-                         in_specs=(P("data"), P("data", None, None),
-                                   P("data", None)),
-                         out_specs=P("data", None), check_rep=False)(
-                             theta, X, y)
+        return jax.shard_map(_local_solver, mesh=mesh,
+                             in_specs=(P("data"), P("data", None, None),
+                                       P("data", None)),
+                             out_specs=P("data", None),
+                             check_vma=False)(theta, X, y)
 
     dec = implicit_diff(spec)(fwd)
     X_sh = jax.device_put(X, NamedSharding(mesh, P("data", None, None)))

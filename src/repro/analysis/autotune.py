@@ -323,10 +323,11 @@ def measure_solver(solver: str, B: int, d: int, *, dtype: str = "float32",
     return cache.put(key, seconds, source="measured", samples=iters)
 
 
-def block_b_candidates(B: int) -> List[int]:
-    """Power-of-two tile heights that divide ``B`` (the sweep grid)."""
-    out = [bb for bb in (1, 2, 4, 8, 16, 32, 64) if bb <= B and B % bb == 0]
-    return out or [1]
+def block_b_candidates(B: int, d: int, dtype: str = "float32") -> List[int]:
+    """The tile heights the compiled kernel accepts at ``(B, d, dtype)``
+    (``kernel.tile_heights``) — the sweep grid."""
+    from repro.kernels.batched_cg.kernel import tile_heights
+    return tile_heights(int(B), int(d), _dtype_bytes(dtype)) or [int(B)]
 
 
 def measure_block_schedule(B: int, d: int, *, dtype: str = "float32",
@@ -357,7 +358,7 @@ def measure_block_schedule(B: int, d: int, *, dtype: str = "float32",
     b = jnp.asarray(b_np)
     out: Dict[int, TuningRecord] = {}
     for bb in (candidates if candidates is not None
-               else block_b_candidates(B)):
+               else block_b_candidates(B, d, dtype)):
         fn = jax.jit(lambda rhs, bb=bb: batched_cg(
             A, rhs, tol=tol, block_b=bb, interpret=interpret))
         seconds = measure(lambda: fn(b), warmup=warmup, iters=iters)
@@ -422,13 +423,15 @@ def predict_solve_seconds(solver: str, B: int, d: int, *,
 # decisions
 # ---------------------------------------------------------------------------
 
-def single_device_solver(spd: bool, d: int, plain: bool = True) -> str:
+def single_device_solver(spd: bool, d: int, plain: bool = True,
+                         dtype: str = "float32") -> str:
     """The single-device registry solver a regime would route to — the
-    comparison point for every sharding decision (mirrors the dense /
-    matrix-free split in ``linear_solve._resolve_auto``)."""
+    comparison point for every sharding decision (the dense / matrix-free
+    split of ``linear_solve._resolve_auto``, which calls this)."""
     from repro.core import linear_solve as ls
     if d <= ls.MAX_DENSE_DIM:
-        return "pallas_cg" if (spd and plain) else "dense_gmres"
+        kernel = spd and plain and _dtype_bytes(dtype) <= 4
+        return "pallas_cg" if kernel else "dense_gmres"
     return "cg" if spd else "normal_cg"
 
 
@@ -465,7 +468,7 @@ def should_shard(B: int, d: int, *, mesh_size: int,
     cache = cache if cache is not None else default_cache()
     backend = backend or current_backend()
     sharded = "sharded_cg" if spd else "sharded_normal_cg"
-    single = single_device_solver(spd, d, plain)
+    single = single_device_solver(spd, d, plain, dtype)
     pc = normalize_precond(precond)
     rec_sh = cache.get(TuningKey(backend, sharded, int(B), int(d), dtype,
                                  int(mesh_size), pc))
@@ -528,19 +531,12 @@ def auto_mesh_size(B: int, d: int, *, max_devices: Optional[int] = None,
 
 def default_block_b(B: int, d: int, *, dtype: str = "float32",
                     pad_lanes: bool = False) -> int:
-    """The untuned tile height: the legacy default 8, shrunk to divide
-    ``B`` and to keep the (block_b, d', d') operator tile inside a
-    conservative VMEM budget (~4 MiB)."""
-    lanes = 128
-    dp = ((d + lanes - 1) // lanes) * lanes if pad_lanes else d
-    budget = 4 * 1024 * 1024
-    bb = 8
-    while bb > 1 and bb * dp * dp * _dtype_bytes(dtype) > budget:
-        bb //= 2
-    bb = min(bb, B)
-    while B % bb:
-        bb -= 1
-    return max(bb, 1)
+    """The untuned tile height: the kernel's tile rule
+    (``kernel.block_rows``) at its default height 8 over the (lane-padded)
+    ``d'`` the kernel will see."""
+    from repro.kernels.batched_cg.kernel import LANES, block_rows
+    dp = -(-d // LANES) * LANES if pad_lanes else d
+    return block_rows(int(B), int(dp), _dtype_bytes(dtype))[0]
 
 
 def choose_block_b(B: int, d: int, *, dtype: str = "float32",
@@ -551,15 +547,14 @@ def choose_block_b(B: int, d: int, *, dtype: str = "float32",
 
     Picks the fastest measured ``variant="block_b=<k>"`` entry for this
     ``(backend, B, d, dtype)`` regime (populated by
-    ``measure_block_schedule`` / the offline sweep); with no
-    measurements, falls back to ``default_block_b`` — i.e. the legacy
-    hardcoded schedule, so ``"auto"`` is never worse than the old
-    default.
+    ``measure_block_schedule`` / the offline sweep) among the heights
+    the kernel accepts; with no measurements, falls back to
+    ``default_block_b``.
     """
     cache = cache if cache is not None else default_cache()
     backend = backend or current_backend()
     measured: Dict[int, float] = {}
-    for bb in block_b_candidates(B):
+    for bb in block_b_candidates(B, d, dtype):
         rec = cache.get(TuningKey(backend, "batched_cg", int(B), int(d),
                                   dtype, 1, "", f"block_b={bb}"))
         if rec is not None and rec.source == "measured":

@@ -53,7 +53,8 @@ from jax import lax
 
 from repro.core import operators
 from repro.core.operators import (LinearOperator, RavelView, _ravel1,
-                                  jacobi_preconditioner, ravel_view)
+                                  dense_matvec, jacobi_preconditioner,
+                                  ravel_view)
 # bottom-adjacent telemetry (imports nothing from repro.core): solve events
 # are staged jit-safely behind the process-level observe() switch — with
 # observability disabled (default) every emission below is a trace-time
@@ -583,7 +584,7 @@ def solve_dense_gmres(matvec: Callable, b, *, init=None, tol: float = 1e-6,
     A, _ = materialize_batched(matvec, b, batch_ndim, view=view)
 
     def dense_mv(vf):                                   # (B, d) -> (B, d)
-        return jnp.einsum("bij,bj->bi", A, vf)
+        return dense_matvec(A, vf)
 
     # "jacobi" reads the diagonal straight off the materialized operator
     # (no extra probing); validation and the safe-diagonal threshold live
@@ -629,7 +630,7 @@ def solve_lu(matvec: Callable, b, *, init=None, tol: float = 1e-6,
     A, view = materialize_batched(matvec, b, batch_ndim)
     x = jnp.linalg.solve(A, view.b[..., None])[..., 0]
     if return_info:
-        rn = jnp.linalg.norm(view.b - jnp.einsum("bij,bj->bi", A, x), axis=-1)
+        rn = jnp.linalg.norm(view.b - dense_matvec(A, x), axis=-1)
         atol = jnp.maximum(tol * jnp.linalg.norm(view.b, axis=-1), 1e-30)
         it = jnp.zeros_like(rn, dtype=jnp.int32)
         # rn <= atol is False for NaN residuals (singular A) — reported honestly
@@ -826,6 +827,10 @@ def solve_pallas_cg(matvec: Callable, b, *, init=None, tol: float = 1e-6,
     the regime was never swept — so the solve service's bucket dispatch
     and ``IterativeSolver``'s backward solve ride tuned schedules with no
     caller changes.  Pass an int to pin the schedule by hand.
+
+    ``info.residual`` is the true residual ``|b - A x|`` the kernel itself
+    stopped on (it recomputes it and restarts CG while it is above
+    ``tol``), so ``info.converged`` reports the kernel's own decision.
     """
     if init is not None:
         raise ValueError("pallas_cg always starts from zero; warm starts "
@@ -842,11 +847,9 @@ def solve_pallas_cg(matvec: Callable, b, *, init=None, tol: float = 1e-6,
             f"pallas_cg materializes dense systems; d={d} exceeds "
             f"MAX_DENSE_DIM={MAX_DENSE_DIM} — use a matrix-free solver")
     A, _ = materialize_batched(matvec, b, batch_ndim, view=view)
-    x = batched_cg(A, view.b, tol=tol, maxiter=maxiter, block_b=block_b,
-                   interpret=interpret)
+    x, rn = batched_cg(A, view.b, tol=tol, maxiter=maxiter, block_b=block_b,
+                       interpret=interpret, return_residual=True)
     if return_info:
-        r = view.b - jnp.einsum("bij,bj->bi", A, x)
-        rn = jnp.linalg.norm(r, axis=-1)
         atol = jnp.maximum(tol * jnp.linalg.norm(view.b, axis=-1), 1e-30)
         info = SolveInfo(iterations=jnp.full_like(rn, -1, dtype=jnp.int32),
                          residual=rn, converged=rn <= atol)
@@ -1039,10 +1042,12 @@ def _resolve_auto(A, example, precond=None, init=None) -> str:
     dispatch.  Materializing fallbacks are never chosen — densifying a
     mesh-placed operator yields per-shard pieces, not the global stack.
 
-    Single-device: the dense small-system regime (d ≤ ``MAX_DENSE_DIM``)
+    Single-device (``autotune.single_device_solver``, which the solve
+    service mirrors): the dense small-system regime (d ≤ ``MAX_DENSE_DIM``)
     auto-materializes: SPD operators take the fused ``pallas_cg`` kernel
     (falling back to the batched ``dense_gmres`` when a preconditioner or a
-    warm start is requested — ``pallas_cg`` supports neither), everything
+    warm start is requested — ``pallas_cg`` supports neither — or when the
+    system is float64, which the compiled kernel refuses), everything
     else ``dense_gmres``.  Above the crossover the solve stays matrix-free:
     ``cg`` only for declared-SPD operators (symmetric alone is not enough —
     CG on a symmetric *indefinite* system can report convergence with a
@@ -1065,10 +1070,12 @@ def _resolve_auto(A, example, precond=None, init=None) -> str:
                 return "sharded_dense_gmres"
             return "sharded_normal_cg"
         return "cg" if spd else "normal_cg"
-    if d <= MAX_DENSE_DIM:
-        plain = precond is None and init is None
-        return "pallas_cg" if spd and plain else "dense_gmres"
-    return "cg" if spd else "normal_cg"
+    from repro.analysis import autotune  # lazy: avoid import cycle
+    leaves = jax.tree_util.tree_leaves(
+        (example, A.example if isinstance(A, LinearOperator) else ()))
+    return autotune.single_device_solver(
+        spd, d, plain=precond is None and init is None,
+        dtype=str(jnp.result_type(*leaves)))
 
 
 # A mesh-placed operator upgrades the classic method names to their
@@ -1120,6 +1127,16 @@ def _upgrade_for_sharded(method, matvec, *, precond=None):
     return method
 
 
+def _emit_dispatch(requested, routed, matvec, b) -> None:
+    """Report a routing decision as a trace-time ``dispatch`` event."""
+    if obs_events.observing():
+        name = lambda s: s if isinstance(s, str) else getattr(
+            s, "__name__", "custom")
+        obs_events.emit("dispatch",
+                        dict(_solve_event_tags(name(routed), matvec, b, {}),
+                             requested=name(requested)))
+
+
 def route_solve(solve, matvec, b, *, tol: float = 1e-6, maxiter: int = 1000,
                 ridge: float = 0.0, precond=None, init=None,
                 return_info: bool = False):
@@ -1149,8 +1166,7 @@ def route_solve(solve, matvec, b, *, tol: float = 1e-6, maxiter: int = 1000,
     returns the per-instance ``SolveInfo``.  Both require a registry
     solver — custom callables own their initialization and diagnostics.
     """
-    requested = solve if isinstance(solve, str) else getattr(
-        solve, "__name__", "custom")
+    requested = solve
     if solve == "auto":
         # _resolve_auto sizes the system from ONE instance: batch-aware
         # operators (batch_ndim == 1, e.g. sharded batched systems) carry
@@ -1160,12 +1176,7 @@ def route_solve(solve, matvec, b, *, tol: float = 1e-6, maxiter: int = 1000,
             example = jax.tree_util.tree_map(lambda l: l[0], b)
         solve = _resolve_auto(matvec, example, precond, init)
     solve = _upgrade_for_sharded(solve, matvec, precond=precond)
-    if obs_events.observing():
-        routed = solve if isinstance(solve, str) else getattr(
-            solve, "__name__", "custom")
-        obs_events.emit("dispatch",
-                        dict(_solve_event_tags(routed, matvec, b, {}),
-                             requested=requested))
+    _emit_dispatch(requested, solve, matvec, b)
     if callable(solve):
         if precond is not None:
             raise ValueError("precond requires a registry solver name; "
@@ -1313,6 +1324,7 @@ def solve(matvec: Callable, b, *, method="cg", batch_axes: Optional[int] = None,
                 f"operator batch_ndim={matvec.batch_ndim} is incompatible "
                 f"with batch_axes={batch_axes}; batch-aware operators carry "
                 "their batch on axis 0")
+    requested = method
     if method == "auto":
         example = b
         if batch_axes is not None:
@@ -1320,6 +1332,7 @@ def solve(matvec: Callable, b, *, method="cg", batch_axes: Optional[int] = None,
                 lambda l: jnp.take(l, 0, axis=int(batch_axes)), b)
         method = _resolve_auto(matvec, example, precond, init)
     method = _upgrade_for_sharded(method, matvec, precond=precond)
+    _emit_dispatch(requested, method, matvec, b)
     if callable(method):
         if batch_axes is not None:
             raise ValueError("batch_axes requires a registry solver name; "

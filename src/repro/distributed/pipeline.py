@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(block_fn: Callable, params_stacked: Any,
@@ -80,7 +79,7 @@ def pipeline_forward(block_fn: Callable, params_stacked: Any,
 
     pspec = jax.tree_util.tree_map(
         lambda l: P(stage_axis, *([None] * (l.ndim - 1))), params_stacked)
-    return shard_map(
+    return jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
-        check_rep=False)(params_stacked, x_microbatches)
+        check_vma=False)(params_stacked, x_microbatches)

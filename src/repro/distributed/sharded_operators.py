@@ -69,7 +69,6 @@ from typing import Any, Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import linear_solve as ls
 from repro.core import operators as ops
@@ -216,7 +215,7 @@ class ShardedOperator(LinearOperator):
 
     def __init__(self, op, mesh: Mesh, in_specs, *, out_specs=None,
                  operands: tuple = (), operand_specs: tuple = (),
-                 reduce: Optional[Callable] = None, check_rep: bool = False):
+                 reduce: Optional[Callable] = None):
         if isinstance(op, LinearOperator):
             if operands:
                 raise ValueError("operands require a factory; a plain "
@@ -241,7 +240,6 @@ class ShardedOperator(LinearOperator):
         self.in_specs = spec_tree(in_specs, template.example)
         self.out_specs = self.in_specs if out_specs is None \
             else spec_tree(out_specs, template.example)
-        self.check_rep = check_rep
         self._psum_axes = instance_axes(self.in_specs, self.batch_ndim)
         self._batch_axes = batch_axes(self.in_specs, self.batch_ndim)
         self._plain = isinstance(op, LinearOperator)
@@ -309,9 +307,10 @@ class ShardedOperator(LinearOperator):
                   out_specs) -> Callable:
         """``shard_map`` ``body(*operands_local, *extra_local)`` on this
         operator's mesh, with the operands automatically prepended."""
-        mapped = shard_map(body, mesh=self.mesh,
-                           in_specs=(*self.operand_specs, *extra_in_specs),
-                           out_specs=out_specs, check_rep=self.check_rep)
+        mapped = jax.shard_map(body, mesh=self.mesh,
+                               in_specs=(*self.operand_specs,
+                                         *extra_in_specs),
+                               out_specs=out_specs, check_vma=False)
         return lambda *extra: mapped(*self.operands, *extra)
 
     # -- LinearOperator protocol -----------------------------------------
@@ -345,7 +344,7 @@ class ShardedOperator(LinearOperator):
             lambda *o: self._factory(*o).transpose(), self.mesh,
             self.out_specs, out_specs=self.in_specs,
             operands=self.operands, operand_specs=self.operand_specs,
-            reduce=self._reduce_arg, check_rep=self.check_rep)
+            reduce=self._reduce_arg)
         out._plain = self._plain    # plain-capture local re-examining
         # survives transposition (the wrapper factory is ours, not a
         # user factory over local operands)

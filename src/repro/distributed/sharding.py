@@ -44,21 +44,6 @@ class ShardingRules:
         return (self.pod, self.data) if self.pod else self.data
 
 
-def abstract_mesh(axis_sizes: Tuple[int, ...], axis_names: Tuple[str, ...]):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    jax ≤ 0.4.x takes one ``((name, size), ...)`` shape tuple; jax ≥ 0.5
-    takes ``(axis_sizes, axis_names)`` positionally.  Shape-only meshes need
-    no physical devices, so spec construction works on any host.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes),
-                                         tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_sizes)))
-
-
 def mesh_axis_size(mesh: Mesh, name) -> int:
     if name is None:
         return 1

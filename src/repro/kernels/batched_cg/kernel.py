@@ -8,14 +8,24 @@ round-trips; here the whole block of systems lives in VMEM and every CG
 iteration is one fused step:
 
   * the batched matvec ``A p`` is a single (block_b, d, d) × (block_b, d)
-    contraction on the MXU,
+    contraction on the MXU, at full f32 precision,
   * the reductions (α, β, residual norms) are VPU row-reductions,
   * per-instance ``active`` masks freeze converged systems while stragglers
-    iterate, and the while_loop exits as soon as the whole block converged.
+    iterate, and the while_loop exits as soon as the whole block converged,
+  * convergence is judged on the true residual ``b - A x``: the recursive
+    residual drifts from it by rounding, so it is recomputed once the
+    recursive one meets ``tol`` and CG restarts where it is still above.
 
-Dense small-system regime: d ≤ 512 (a (8, 512, 512) f32 block of operators is
-8 MB — comfortably VMEM-resident next to the CG vectors).  For larger or
-matrix-free systems use the masked solvers in ``repro.core.linear_solve``.
+Dense small-system regime: d ≤ 512.  The operator block is double-buffered
+in VMEM, so an (8, 512, 512) f32 block takes 16 MiB, which with the other
+buffers is more than the 16 MiB scoped-VMEM default of a v5e: that is why the kernel asks for
+``VMEM_LIMIT_BYTES`` and ``block_rows`` sizes the block against
+``BLOCK_BUDGET_BYTES``.  For larger or matrix-free systems use the masked
+solvers in ``repro.core.linear_solve``.
+
+The compiled kernel computes in 32-bit: float64 inputs are refused (the
+TPU's Mosaic compiler has no 64-bit vector types); interpret mode keeps
+the input precision.
 """
 from __future__ import annotations
 
@@ -25,26 +35,26 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _batched_cg_kernel(a_ref, b_ref, x_ref, *, tol: float, maxiter: int):
-    # compute in the input precision, floored at f32 (so f64 solves under
-    # jax_enable_x64 keep f64 accuracy instead of silently degrading)
+def _batched_cg_kernel(a_ref, b_ref, x_ref, rn_ref, *, tol: float,
+                       maxiter: int):
+    # compute in the input precision, floored at f32 (interpret mode keeps
+    # f64 solves under jax_enable_x64 at f64; the compiled path is f32)
     dtype = jnp.promote_types(jnp.result_type(a_ref.dtype, b_ref.dtype),
                               jnp.float32)
-    A = a_ref[...].astype(dtype)                        # (bb, d, d)
     b = b_ref[...].astype(dtype)                        # (bb, d)
 
     def matvec(p):                                      # (bb, d) -> (bb, d)
+        # read the operator block from its VMEM buffer on every step: no
+        # second block-sized copy lives across the loop
         return lax.dot_general(
-            A, p,
+            a_ref[...].astype(dtype), p,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            precision=lax.Precision.HIGHEST,
             preferred_element_type=dtype)
 
-    x0 = jnp.zeros_like(b)
-    r0 = b                                              # r = b - A·0
-    p0 = r0
-    rs0 = jnp.sum(r0 * r0, axis=-1)                     # (bb,)
     b2 = jnp.sum(b * b, axis=-1)
     atol2 = jnp.maximum(tol * tol * b2, 1e-30)
 
@@ -68,11 +78,68 @@ def _batched_cg_kernel(a_ref, b_ref, x_ref, *, tol: float, maxiter: int):
         rs = jnp.where(active, rs_new, rs)
         return x, r, p, rs, k + 1
 
-    x, _, _, _, _ = lax.while_loop(cond, body, (x0, r0, p0, rs0, 0))
+    def replace_residual(state):
+        # run CG until the recursive residuals meet tol, then replace them
+        # with the true residuals b - A x and restart CG from x wherever
+        # rounding left those above tol
+        x, _, _, _, k = lax.while_loop(cond, body, state)
+        r = b - matvec(x)
+        return x, r, r, jnp.sum(r * r, axis=-1), k
+
+    x0 = jnp.zeros_like(b)                              # r = b - A·0 = b
+    x, _, _, rs, _ = lax.while_loop(
+        cond, replace_residual, (x0, b, b, b2, jnp.int32(0)))
     x_ref[...] = x.astype(x_ref.dtype)
+    # the true residual norm each row stopped on, across the lane tile
+    rn_ref[...] = jnp.broadcast_to(jnp.sqrt(rs)[:, None],
+                                   rn_ref.shape).astype(rn_ref.dtype)
 
 
 LANES = 128     # TPU vector-lane width: the last dim of a VMEM tile
+SUBLANES = 8    # sublane height: the second-to-last dim of a VMEM tile
+#: scoped VMEM the compiled kernel asks for (a v5e core has 128 MiB)
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+#: what the double-buffered (block_b, d, d) operator block may take of it
+BLOCK_BUDGET_BYTES = 32 * 1024 * 1024
+
+
+def tile_heights(B: int, d: int, itemsize: int = 4) -> list:
+    """Every block height the compiled kernel accepts for a (B, d) batch.
+
+    The (block_b, d) right-hand-side block needs ``block_b`` to be a
+    multiple of ``SUBLANES`` or the whole batch, ``block_b`` must divide
+    ``B``, and the double-buffered operator block (plus its f32 copy when
+    the input is narrower) must fit ``BLOCK_BUDGET_BYTES``.
+    """
+    per_row = d * d * (2 * itemsize + (4 if itemsize < 4 else 0))
+    heights = [bb for bb in range(SUBLANES, B + 1, SUBLANES) if B % bb == 0]
+    if B not in heights:
+        heights.append(B)
+    return [bb for bb in heights if bb * per_row <= BLOCK_BUDGET_BYTES]
+
+
+def block_rows(B: int, d: int, itemsize: int = 4,
+               want: int = SUBLANES) -> tuple:
+    """The tile rule: ``(block_b, B_padded)`` for a (B, d) batch.
+
+    ``block_b`` is the largest legal height (``tile_heights``) not above
+    ``want``, else the smallest legal one.  When no height divides ``B``
+    within the budget (e.g. B=100 at d=512), the batch is padded to the next
+    multiple of ``SUBLANES`` — with identity systems and zero right-hand
+    sides, which converge at loop entry — and tiled by that.
+    """
+    Bp = B
+    heights = tile_heights(B, d, itemsize)
+    if not heights:
+        Bp = -(-B // SUBLANES) * SUBLANES
+        heights = tile_heights(Bp, d, itemsize)
+    if not heights:
+        raise ValueError(
+            f"no batched-CG block of {d}x{d} systems ({itemsize}-byte) fits "
+            f"the {BLOCK_BUDGET_BYTES >> 20} MiB VMEM budget; solve d={d} "
+            "with a matrix-free solver")
+    below = [bb for bb in heights if bb <= want]
+    return (max(below) if below else min(heights)), Bp
 
 
 def pad_to_lanes(A, b, lanes: int = LANES):
@@ -102,35 +169,60 @@ def pad_to_lanes(A, b, lanes: int = LANES):
 
 
 def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
-                      block_b: int = 8, interpret: bool = False,
+                      block_b: int = SUBLANES, interpret: bool = False,
                       pad_lanes: bool = False):
-    """A: (B, d, d) SPD batch; b: (B, d).  Returns x: (B, d) with A x ≈ b.
+    """A: (B, d, d) SPD batch; b: (B, d).  Returns ``(x, rn)``: x (B, d)
+    with A x ≈ b, and the (B,) true residual norms the systems stopped on.
 
-    ``pad_lanes=True`` embeds systems whose d is not a multiple of the
-    128-lane VMEM tile width into the next lane multiple (identity pad —
-    see ``pad_to_lanes``) and slices the solution back.
+    Each system runs CG until its recursive residual meets ``tol``, then
+    the kernel recomputes the true residual ``b - A x`` and restarts CG
+    from ``x`` while that one is above ``tol`` (residual replacement), all
+    within the ``maxiter`` step budget.
+
+    ``block_b`` is the wanted tile height; ``block_rows`` legalizes it (and
+    pads the batch when it must), in interpret mode too, so CPU tests run
+    the schedule the chip runs.  ``pad_lanes=True`` embeds systems whose d
+    is not a multiple of the 128-lane VMEM tile width into the next lane
+    multiple (identity pad — see ``pad_to_lanes``) and slices the solution
+    back.
     """
     if pad_lanes:
         A, b, d0 = pad_to_lanes(A, b)
-        x = batched_cg_pallas(A, b, tol=tol, maxiter=maxiter,
-                              block_b=block_b, interpret=interpret)
-        return x[:, :d0]
+        x, rn = batched_cg_pallas(A, b, tol=tol, maxiter=maxiter,
+                                  block_b=block_b, interpret=interpret)
+        return x[:, :d0], rn
     B, d, d2 = A.shape
     assert d == d2, (d, d2)
     assert b.shape == (B, d), (A.shape, b.shape)
-    block_b = min(block_b, B)
-    assert B % block_b == 0, (B, block_b)
+    itemsize = jnp.dtype(A.dtype).itemsize
+    if not interpret and max(itemsize, jnp.dtype(b.dtype).itemsize) > 4:
+        raise TypeError(
+            f"the compiled batched-CG kernel computes in 32-bit; got "
+            f"{A.dtype}/{b.dtype} — route float64 systems to dense_gmres or "
+            "cg (method='auto' does)")
+    block_b, Bp = block_rows(B, d, itemsize, int(block_b))
+    if Bp != B:
+        eye = jnp.broadcast_to(jnp.eye(d, dtype=A.dtype), (Bp - B, d, d))
+        A = jnp.concatenate([A, eye])
+        b = jnp.pad(b, ((0, Bp - B), (0, 0)))
+    rn_dtype = jnp.promote_types(jnp.result_type(A.dtype, b.dtype),
+                                 jnp.float32)
     kernel = functools.partial(_batched_cg_kernel, tol=tol, maxiter=maxiter)
-    return pl.pallas_call(
+    x, rn = pl.pallas_call(
         kernel,
-        grid=(B // block_b,),
+        grid=(Bp // block_b,),
         in_specs=[pl.BlockSpec((block_b, d, d), lambda i: (i, 0, 0)),
                   pl.BlockSpec((block_b, d), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, d), b.dtype),
+        out_specs=[pl.BlockSpec((block_b, d), lambda i: (i, 0)),
+                   pl.BlockSpec((block_b, LANES), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Bp, d), b.dtype),
+                   jax.ShapeDtypeStruct((Bp, LANES), rn_dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(   # whole-call totals, worst case
-            flops=2 * maxiter * B * d * d,
-            bytes_accessed=4 * (B * d * d + 2 * B * d),
+            flops=2 * maxiter * Bp * d * d,
+            bytes_accessed=itemsize * (Bp * d * d + 2 * Bp * d),
             transcendentals=0),
         interpret=interpret,
     )(A, b)
+    return x[:B], rn[:B, 0]

@@ -1,7 +1,8 @@
 """Public batched-CG op with implicit-differentiation custom VJP.
 
 Forward: one fused Pallas kernel solves the whole (B, d, d) batch of SPD
-systems (``ref.py`` fallback off-TPU / in tests).  Backward: instead of
+systems on the TPU (``ref.py`` on other backends, interpret mode on
+request).  Backward: instead of
 differentiating through the CG iterations, we apply the paper's move at the
 kernel boundary — x = A⁻¹b is implicitly defined by Ax − b = 0, so
 
@@ -23,31 +24,25 @@ from repro.kernels.batched_cg.kernel import batched_cg_pallas
 from repro.kernels.batched_cg.ref import batched_cg_ref
 
 
-def _pick_block_b(B: int, block_b: int) -> int:
-    bb = min(block_b, B)
-    while B % bb:
-        bb -= 1
-    return max(bb, 1)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
 def _solve(A, b, tol, maxiter, block_b, interpret, pad_lanes):
+    """``(x, rn)``: the solutions and the true residual norms they stopped
+    on.  ``rn`` is a diagnostic: the custom VJP ignores its cotangent."""
     if interpret is None:      # no TPU: identical masked-CG reference path
         return batched_cg_ref(A, b, tol=tol, maxiter=maxiter)
-    return batched_cg_pallas(A, b, tol=tol, maxiter=maxiter,
-                             block_b=_pick_block_b(A.shape[0], block_b),
+    return batched_cg_pallas(A, b, tol=tol, maxiter=maxiter, block_b=block_b,
                              interpret=interpret, pad_lanes=pad_lanes)
 
 
 def _fwd(A, b, tol, maxiter, block_b, interpret, pad_lanes):
-    x = _solve(A, b, tol, maxiter, block_b, interpret, pad_lanes)
-    return x, (A, x)
+    x, rn = _solve(A, b, tol, maxiter, block_b, interpret, pad_lanes)
+    return (x, rn), (A, x)
 
 
 def _bwd(tol, maxiter, block_b, interpret, pad_lanes, res, g):
     A, x = res
-    u = _solve(A.transpose(0, 2, 1), g, tol, maxiter, block_b, interpret,
-               pad_lanes)
+    u, _ = _solve(A.transpose(0, 2, 1), g[0], tol, maxiter, block_b,
+                  interpret, pad_lanes)
     dA = -u[:, :, None] * x[:, None, :]
     return dA, u
 
@@ -57,7 +52,7 @@ _solve.defvjp(_fwd, _bwd)
 
 def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
                block_b=8, interpret: Optional[bool] = None,
-               pad_lanes: bool = False):
+               pad_lanes: bool = False, return_residual: bool = False):
     """Solve the batch of SPD systems A[i] x[i] = b[i] in one fused kernel.
 
     Args:
@@ -68,17 +63,22 @@ def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
       b: (B, d) right-hand sides ((batched) pytree for operator input).
       tol: relative residual tolerance per instance.
       maxiter: CG iteration cap (default: d, the exact-arithmetic bound).
-      block_b: instances per Pallas program (VMEM tile height), or
+      block_b: wanted instances per Pallas program (VMEM tile height,
+        legalized by ``kernel.block_rows``), or
         ``"auto"`` to resolve a tuned tile for this ``(backend, B, d,
         dtype)`` from the autotuning cache (host-side, at trace time;
         falls back to the legacy default-8 schedule when the regime was
         never swept — see ``analysis.autotune.choose_block_b``).
       interpret: True forces Pallas interpret mode; None auto-selects the
-        pure-JAX reference path off-TPU and the compiled kernel on TPU.
+        pure-JAX reference path off-TPU and the compiled kernel on TPU
+        (which refuses float64 inputs — ``method="auto"`` routes those to
+        ``dense_gmres``).
       pad_lanes: embed d into the next multiple of the 128-lane VMEM tile
         width (identity pad, exact — see ``kernel.pad_to_lanes``) before
         the Pallas call; ignored on the reference path, which has no
         tiling constraint.
+      return_residual: also return the per-instance true residual norms
+        ``|b - A x|`` that convergence was judged on (not differentiated).
 
     Differentiable in A and b via the implicit-diff custom VJP (operator
     input: in b, through the materialized matrix).
@@ -91,10 +91,12 @@ def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
         dense = A.materialize()
         if A.batch_ndim == 0:
             dense = dense[None]
-        x = batched_cg(dense, view.b, tol=tol, maxiter=maxiter,
-                       block_b=block_b, interpret=interpret,
-                       pad_lanes=pad_lanes)
-        return view.to_tree(x)
+        x, rn = batched_cg(dense, view.b, tol=tol, maxiter=maxiter,
+                           block_b=block_b, interpret=interpret,
+                           pad_lanes=pad_lanes, return_residual=True)
+        if A.batch_ndim == 0:
+            rn = rn[0]
+        return (view.to_tree(x), rn) if return_residual else view.to_tree(x)
     B, d, _ = A.shape
     if maxiter is None:
         maxiter = d
@@ -108,5 +110,6 @@ def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
         interpret = None   # sentinel: ref path (see _solve)
     elif interpret is None:
         interpret = False
-    return _solve(A, b, float(tol), int(maxiter), int(block_b), interpret,
-                  bool(pad_lanes))
+    x, rn = _solve(A, b, float(tol), int(maxiter), int(block_b), interpret,
+                   bool(pad_lanes))
+    return (x, rn) if return_residual else x

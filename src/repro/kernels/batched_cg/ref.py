@@ -1,8 +1,9 @@
 """Pure-JAX reference for the fused batched-CG kernel.
 
 Same algorithm as ``kernel.py`` — masked CG over a (B, d) batch inside one
-``lax.while_loop`` — expressed with plain jnp ops.  Used as the correctness
-oracle for kernel parity tests and as the CPU/GPU fallback path.
+``lax.while_loop``, with residual replacement — expressed with plain jnp
+ops.  Used as the correctness oracle for kernel parity tests and as the
+CPU/GPU fallback path.
 """
 from __future__ import annotations
 
@@ -10,19 +11,19 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core.operators import dense_matvec
+
 
 @jax.jit
 def batched_cg_ref(A, b, tol: float = 1e-6, maxiter: int = 64):
-    """A: (B, d, d) SPD batch; b: (B, d).  Returns x: (B, d)."""
+    """A: (B, d, d) SPD batch; b: (B, d).  Returns ``(x, rn)``: x (B, d)
+    and the (B,) true residual norms the systems stopped on."""
     dtype = jnp.promote_types(jnp.result_type(A.dtype, b.dtype), jnp.float32)
     out_dtype = b.dtype
     A = A.astype(dtype)
     b = b.astype(dtype)
-    x0 = jnp.zeros_like(b)
-    r0 = b
-    p0 = r0
-    rs0 = jnp.sum(r0 * r0, axis=-1)
-    atol2 = jnp.maximum(tol * tol * jnp.sum(b * b, axis=-1), 1e-30)
+    b2 = jnp.sum(b * b, axis=-1)
+    atol2 = jnp.maximum(tol * tol * b2, 1e-30)
 
     def cond(state):
         _, _, _, rs, k = state
@@ -31,7 +32,7 @@ def batched_cg_ref(A, b, tol: float = 1e-6, maxiter: int = 64):
     def body(state):
         x, r, p, rs, k = state
         active = rs > atol2
-        ap = jnp.einsum("bij,bj->bi", A, p)
+        ap = dense_matvec(A, p)
         denom = jnp.sum(p * ap, axis=-1)
         safe = jnp.where(denom == 0, 1.0, denom)
         alpha = jnp.where(denom == 0, 0.0, rs / safe)
@@ -44,5 +45,11 @@ def batched_cg_ref(A, b, tol: float = 1e-6, maxiter: int = 64):
         rs = jnp.where(active, rs_new, rs)
         return x, r, p, rs, k + 1
 
-    x, _, _, _, _ = lax.while_loop(cond, body, (x0, r0, p0, rs0, 0))
-    return x.astype(out_dtype)
+    def replace_residual(state):
+        x, _, _, _, k = lax.while_loop(cond, body, state)
+        r = b - dense_matvec(A, x)
+        return x, r, r, jnp.sum(r * r, axis=-1), k
+
+    x, _, _, rs, _ = lax.while_loop(cond, replace_residual,
+                                    (jnp.zeros_like(b), b, b, b2, 0))
+    return x.astype(out_dtype), jnp.sqrt(rs)

@@ -7,7 +7,8 @@ LM decode (batched prefill + decode with a KV cache)::
 
 Solve service (continuous-batching linear-solve front end; drives two
 traffic waves — the second replays the first, so the warm-start cache
-hit rate and scheduler metrics are exercised end to end)::
+hit rate and scheduler metrics are exercised end to end; each wave is
+submitted whole and flushed, so its buckets fill to ``--max-batch``)::
 
     PYTHONPATH=src python -m repro.launch.serve --solve-service \
         --requests 64 --dim 32 --max-batch 64
@@ -25,7 +26,37 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import decode_step, init_decode_state, init_params
+
+
+def drive_service(problems, *, max_batch: int = 64,
+                  cache_capacity: int = 256):
+    """Serve SPD ``problems`` ``[(A, b), ...]`` in two waves; the
+    solve-service path of this launcher.
+
+    Each wave submits every problem to one ``SolveService`` (warm-start
+    cache on) and flushes, so the warm wave replays the cold one and hits
+    the cache.  Returns ``(svc, stats)``: one dict per wave with its ``wave``
+    name, ``results`` (``ServiceResult`` per problem, in order),
+    ``seconds`` from first submit to last result, and the service's
+    ``metrics_summary()`` after the wave.  Telemetry follows the caller's
+    ``repro.observability.observe`` switch.
+    """
+    from repro.runtime.solve_service import SolveService, WarmStartCache
+
+    svc = SolveService(max_batch=max_batch,
+                       cache=WarmStartCache(capacity=cache_capacity))
+    stats = []
+    for wave in ("cold", "warm"):
+        t0 = time.perf_counter()
+        futs = [svc.submit(A, b, positive_definite=True)
+                for A, b in problems]
+        svc.flush()
+        results = [f.result() for f in futs]
+        stats.append(dict(svc.metrics_summary(), wave=wave, results=results,
+                          seconds=time.perf_counter() - t0))
+    return svc, stats
 
 
 def serve_solves(args) -> None:
@@ -40,7 +71,6 @@ def serve_solves(args) -> None:
     import numpy as np
 
     from repro import observability as obs
-    from repro.runtime.solve_service import SolveService, WarmStartCache
 
     rng = np.random.default_rng(args.seed)
     n, d = args.requests, args.dim
@@ -52,24 +82,15 @@ def serve_solves(args) -> None:
     # enable BEFORE constructing the service: programs jitted while
     # disabled would stay uninstrumented until re-traced
     with obs.observe(enabled=True, trace_path=args.trace):
-        svc = SolveService(max_batch=args.max_batch,
-                           cache=WarmStartCache(
-                               capacity=args.cache_capacity))
-        svc.start()                   # background scheduler thread
-        try:
-            for wave in ("cold", "warm"):   # wave 2 replays wave 1: hits
-                t0 = time.perf_counter()
-                futs = [svc.submit(A, b, positive_definite=True)
-                        for A, b in problems]
-                results = [f.result(timeout=60.0) for f in futs]
-                dt = time.perf_counter() - t0
-                iters = [int(r.info.iterations) for r in results]
-                print(f"[serve] {wave}: {n} requests d={d} in "
-                      f"{dt*1e3:.1f}ms ({n / dt:.0f} req/s) "
-                      f"iters(median)={int(np.median(iters))} "
-                      f"warm_started={sum(r.warm_start for r in results)}")
-        finally:
-            svc.stop()
+        svc, stats = drive_service(problems, max_batch=args.max_batch,
+                                   cache_capacity=args.cache_capacity)
+        for st in stats:
+            results, dt = st["results"], st["seconds"]
+            iters = [int(r.info.iterations) for r in results]
+            print(f"[serve] {st['wave']}: {n} requests d={d} in "
+                  f"{dt*1e3:.1f}ms ({n / dt:.0f} req/s) "
+                  f"iters(median)={int(np.median(iters))} "
+                  f"warm_started={sum(r.warm_start for r in results)}")
         snap = svc.metrics_snapshot()
 
         def _val(name, default=0.0):
@@ -118,6 +139,7 @@ def main():
                     help="solve-service: write a JSONL span/event trace "
                          "(summarize with repro.observability.report)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.solve_service:
         serve_solves(args)

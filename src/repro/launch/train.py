@@ -20,6 +20,7 @@ from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticLMStream
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw, schedules
 from repro.runtime import (PreemptionHandler, StragglerMonitor,
                            TrainStepConfig, make_train_state,
@@ -54,7 +55,7 @@ def main():
 
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         rules = shd.ShardingRules()
         state0 = make_train_state(cfg, optimizer, jax.random.PRNGKey(
             args.seed), compress=args.compress_grads)

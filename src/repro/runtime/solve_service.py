@@ -515,20 +515,23 @@ class SolveService:
                 "systems directly through linear_solve.solve")
         return A_host, b_flat, unravel, symmetric, bool(positive_definite)
 
-    def _resolve_solver(self, positive_definite: bool, precond) -> str:
+    def _resolve_solver(self, positive_definite: bool, precond, d: int,
+                        dtype: str) -> str:
         """Resolve ``"auto"`` ONCE at admission so bucket keys are stable.
 
-        This is ``linear_solve._resolve_auto`` restricted to the service's
-        regime (single-device dense, ``d ≤ MAX_DENSE_DIM``), evaluated
-        host-side so admission stays off the JAX dispatch path — a test
-        pins it against the real resolver.  With the warm-start cache
-        enabled the resolution assumes an ``init`` may arrive (steering
-        off ``pallas_cg``, which always starts from zero) — cold and warm
+        The single-device rule ``linear_solve._resolve_auto`` applies
+        (``autotune.single_device_solver``), evaluated host-side so
+        admission stays off the JAX dispatch path — a test pins the two
+        against each other.  With the warm-start cache enabled the
+        resolution assumes an ``init`` may arrive (steering off
+        ``pallas_cg``, which always starts from zero) — cold and warm
         requests for the same problem must land in the SAME bucket and
         reuse one compiled program.
         """
+        from repro.analysis import autotune
         plain = precond is None and self.cache is None
-        return "pallas_cg" if positive_definite and plain else "dense_gmres"
+        return autotune.single_device_solver(positive_definite, d, plain,
+                                             dtype)
 
     def _enqueue(self, pending: _PendingRequest) -> Future:
         pending.enqueue_t = time.perf_counter()
@@ -547,9 +550,11 @@ class SolveService:
         A_dense, b_flat, unravel, sym, pd = self._admit_operator(
             A, b, symmetric, positive_definite)
         d = int(b_flat.shape[0])
+        dtype = str(jax.dtypes.canonicalize_dtype(
+            np.result_type(A_dense.dtype, b_flat.dtype)))
         solver = r["solve"]
         if solver == "auto":
-            solver = self._resolve_solver(pd, r["precond"])
+            solver = self._resolve_solver(pd, r["precond"], d, dtype)
         # admission-time mirror of linear_solve._check_operator_routing:
         # an unknown solver name or a symmetric-only solver paired with a
         # declared-nonsymmetric operator must fail HERE, in the caller's
@@ -563,11 +568,8 @@ class SolveService:
                 f"(positive_definite={pd}) — route a general solver "
                 "(gmres/bicgstab/normal_cg/dense_gmres) instead, or fix "
                 "the declared flags if the operator really is symmetric")
-        dtype = jax.dtypes.canonicalize_dtype(
-            np.result_type(A_dense.dtype, b_flat.dtype))
         key = BucketKey(d=d, solver=solver, precond=r["precond"],
-                        symmetric=sym, positive_definite=pd,
-                        dtype=str(dtype),
+                        symmetric=sym, positive_definite=pd, dtype=dtype,
                         tol=r["tol"], maxiter=r["maxiter"], ridge=r["ridge"],
                         backward=backward, backward_iters=backward_iters)
         fingerprint = init = None

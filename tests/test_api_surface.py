@@ -131,15 +131,17 @@ def test_runtime_service_public_surface():
     from repro.core.linear_solve import _resolve_auto
     svc_cold = rt.SolveService(cache=None)
     svc_warm = rt.SolveService()
-    b = jnp.ones(8)
-    for pd in (True, False):
-        for precond in (None, "jacobi"):
-            op = DenseOperator(jnp.eye(8), symmetric=True,
-                               positive_definite=pd)
-            assert svc_cold._resolve_solver(pd, precond) == \
-                _resolve_auto(op, b, precond, None)
-            assert svc_warm._resolve_solver(pd, precond) == \
-                _resolve_auto(op, b, precond, b)
+    for dtype in (jnp.float32, jnp.float64):
+        b = jnp.ones(8, dtype)
+        for pd in (True, False):
+            for precond in (None, "jacobi"):
+                op = DenseOperator(jnp.eye(8, dtype=dtype), symmetric=True,
+                                   positive_definite=pd)
+                name = str(b.dtype)
+                assert svc_cold._resolve_solver(pd, precond, 8, name) == \
+                    _resolve_auto(op, b, precond, None)
+                assert svc_warm._resolve_solver(pd, precond, 8, name) == \
+                    _resolve_auto(op, b, precond, b)
 
 
 def test_backward_mode_surface():

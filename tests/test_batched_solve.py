@@ -224,9 +224,9 @@ class TestPallasBatchedCG:
         As = _spd_batch(rng, B, d).astype(jnp.float32)
         bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
                                jnp.float32)
-        out = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
-                                block_b=block_b, interpret=True)
-        ref = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
+        out, _ = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
+                                   block_b=block_b, interpret=True)
+        ref, _ = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
@@ -235,7 +235,7 @@ class TestPallasBatchedCG:
         As = _spd_batch(rng, B, d).astype(jnp.float32)
         bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
                                jnp.float32)
-        x = batched_cg_ref(As, bs, tol=1e-8, maxiter=4 * d)
+        x, _ = batched_cg_ref(As, bs, tol=1e-8, maxiter=4 * d)
         res = jnp.linalg.norm(jnp.einsum("bij,bj->bi", As, x) - bs, axis=-1)
         rel = res / jnp.linalg.norm(bs, axis=-1)
         assert float(jnp.max(rel)) < 1e-5
@@ -269,6 +269,35 @@ class TestPallasBatchedCG:
         res = jnp.linalg.norm(jnp.einsum("bij,bj->bi", As, x) - bs, axis=-1)
         rel = res / jnp.linalg.norm(bs, axis=-1)
         assert float(jnp.max(rel)) < 1e-4
+
+    @pytest.mark.parametrize("interpret", [None, True])
+    def test_converged_on_true_residual_f32(self, rng, interpret):
+        """f32 CG at tol=1e-6 on cond-100 systems: the recursive residual
+        drifts below the true one, so stopping on it alone leaves every
+        true residual here above tol (1.3-4.2x).  With residual replacement
+        every system converges on its true residual."""
+        from repro.core import DenseOperator
+        B, d, tol = 8, 64, 1e-6
+
+        def one(k):
+            Q, _ = jnp.linalg.qr(jax.random.normal(k, (d, d)))
+            return (Q * jnp.logspace(0.0, 2.0, d)) @ Q.T
+
+        As = jax.vmap(one)(jax.random.split(rng, B))
+        As = ((As + As.transpose(0, 2, 1)) / 2).astype(jnp.float32)
+        bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
+                               jnp.float32)
+        x, info = ls.solve(DenseOperator(As, positive_definite=True), bs,
+                           method="pallas_cg", tol=tol, maxiter=10 * d,
+                           return_info=True, interpret=interpret)
+        A64, b64 = np.asarray(As, np.float64), np.asarray(bs, np.float64)
+        atol = tol * np.linalg.norm(b64, axis=-1)
+        true = np.linalg.norm(
+            b64 - np.einsum("bij,bj->bi", A64, np.asarray(x, np.float64)),
+            axis=-1)
+        assert bool(np.all(np.asarray(info.converged)))
+        assert np.all(np.asarray(info.residual) <= atol)
+        assert np.all(true <= atol), true / atol
 
     def test_dense_dim_guard(self, rng):
         d = ls.MAX_DENSE_DIM + 1
@@ -305,10 +334,10 @@ class TestLanePadding:
         As = _spd_batch(rng, B, d).astype(jnp.float32)
         bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
                                jnp.float32)
-        out = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
-                                block_b=block_b, interpret=True,
-                                pad_lanes=True)
-        ref = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
+        out, _ = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
+                                   block_b=block_b, interpret=True,
+                                   pad_lanes=True)
+        ref, _ = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
         assert out.shape == (B, d)      # solution sliced back to d
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)

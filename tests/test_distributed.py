@@ -64,7 +64,7 @@ class TestSpecConstruction:
 
     def test_moe_expert_dim_on_model_when_divisible(self):
         # shape-only: AbstractMesh needs no physical devices
-        mesh = shd.abstract_mesh((1, 16), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((1, 16), ("data", "model"))
         rules = shd.ShardingRules()
         s = shd.param_spec(("blocks", "mlp", "w_gate"), (160, 5120, 1536),
                            rules, mesh)
@@ -89,7 +89,7 @@ class TestSpecConstruction:
     def test_all_archs_specs_constructible(self):
         """Spec construction must succeed for every assigned arch (full-size
         configs — shapes only, no allocation)."""
-        mesh = shd.abstract_mesh((1, 16), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((1, 16), ("data", "model"))
         from repro.models import model as mdl
         for name in configs.names():
             cfg = configs.get(name)
@@ -229,7 +229,8 @@ class TestShardedImplicitDiff:
             from jax.sharding import PartitionSpec as P, NamedSharding
             jax.config.update("jax_enable_x64", True)
             from repro.core import custom_root
-            mesh = jax.make_mesh((8,), ("data",))
+            from repro.launch.mesh import make_solve_mesh
+            mesh = make_solve_mesh(8)
             m, d = 64, 16
             key = jax.random.PRNGKey(0)
             X = jax.random.normal(key, (m, d))

@@ -32,6 +32,7 @@ from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, SyntheticLMStream
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.optim import sgd
 from repro.optim.optimizer import OptState
 from repro.runtime import TrainStepConfig, TrainState, make_train_state, \\
@@ -46,7 +47,7 @@ opt = sgd(1e-2, momentum=0.0)
 step = make_train_step(cfg, opt, TrainStepConfig(remat=False))
 state = make_train_state(cfg, opt, jax.random.PRNGKey(0))
 
-mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+mesh = make_mesh(mesh_shape, ("data", "model"))
 rules = shd.ShardingRules()
 pspecs = shd.params_specs(state.params, rules, mesh)
 sspec = TrainState(params=pspecs,

@@ -301,17 +301,24 @@ class TestRouting:
             ls.solve(A, jnp.ones((2, 4)), method="cg", batch_axes=0)
 
     def test_auto_dispatch_small_vs_large(self, rng):
-        spd_small = ops.DenseOperator(_spd(rng, 8), positive_definite=True)
+        spd_small = ops.DenseOperator(_spd(rng, 8).astype(jnp.float32),
+                                      positive_definite=True)
         gen_small = ops.DenseOperator(jnp.asarray(rng.randn(8, 8)) +
                                       8 * jnp.eye(8), symmetric=False)
-        assert ls._resolve_auto(spd_small, jnp.zeros(8)) == "pallas_cg"
-        assert ls._resolve_auto(gen_small, jnp.zeros(8)) == "dense_gmres"
+        f32 = jnp.zeros(8, jnp.float32)
+        assert ls._resolve_auto(spd_small, f32) == "pallas_cg"
+        assert ls._resolve_auto(gen_small, f32) == "dense_gmres"
+        # the compiled kernel is 32-bit: float64 systems stay on XLA
+        spd_f64 = ops.DenseOperator(_spd(rng, 8).astype(jnp.float64),
+                                    positive_definite=True)
+        assert ls._resolve_auto(spd_f64, jnp.zeros(8, jnp.float64)) == \
+            "dense_gmres"
         # a requested preconditioner or warm start steers SPD small systems
         # off pallas_cg (which supports neither) onto dense_gmres
-        assert ls._resolve_auto(spd_small, jnp.zeros(8),
+        assert ls._resolve_auto(spd_small, f32,
                                 precond="jacobi") == "dense_gmres"
-        assert ls._resolve_auto(spd_small, jnp.zeros(8),
-                                init=jnp.ones(8)) == "dense_gmres"
+        assert ls._resolve_auto(spd_small, f32,
+                                init=jnp.ones(8, jnp.float32)) == "dense_gmres"
         big = jnp.zeros(ls.MAX_DENSE_DIM + 1)
         spd_big = ops.FunctionOperator(lambda v: 2.0 * v, big,
                                        positive_definite=True)

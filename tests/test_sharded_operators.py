@@ -279,7 +279,7 @@ class TestShardedSolvers:
         # (dtype included — the suite runs under x64).
         Bn, dd, dtype = autotune.operator_regime(spd)
         backend = autotune.current_backend()
-        single = autotune.single_device_solver(True, dd)
+        single = autotune.single_device_solver(True, dd, dtype=dtype)
 
         def seeded(sharded_ratio):
             c = autotune.TuningCache()
@@ -466,16 +466,15 @@ class TestShardedImplicitDiff:
         """With the forward solve on the mesh too, the whole compiled grad
         contains NO all-gather: the backward solve runs per shard and only
         the loss/psum reductions cross devices."""
-        from jax.experimental.shard_map import shard_map
         X, y, spec, _, theta = self._problem(rng, mesh)
 
         def sharded_solver(init, theta, X, y):
-            return shard_map(
+            return jax.shard_map(
                 lambda t, Xl, yl: _direct_ridge_solver(None, t, Xl, yl),
                 mesh=mesh,
                 in_specs=(P("data"), P("data", None, None),
                           P("data", None)),
-                out_specs=P("data", None), check_rep=False)(theta, X, y)
+                out_specs=P("data", None), check_vma=False)(theta, X, y)
 
         dec = implicit_diff(spec)(sharded_solver)
         t_sh = _put(mesh, theta, P("data"))
