@@ -231,7 +231,8 @@ class SolveInfo(NamedTuple):
 
     ``iterations`` counts the solver's outer steps: matvec iterations for
     cg/normal_cg/bicgstab, *restart cycles* (each up to ``restart`` Arnoldi
-    steps) for gmres, 0 for direct solves, -1 when untracked (pallas_cg).
+    steps) for gmres, 0 for direct solves, and for pallas_cg each system's
+    own CG steps, counted by the kernel.
     """
     iterations: jnp.ndarray    # outer steps actually spent per instance
     residual: jnp.ndarray      # final ||b - A x|| per instance
@@ -240,6 +241,10 @@ class SolveInfo(NamedTuple):
     # returned (co)tangent — populated by the approximate backward modes (and
     # by exact solves when error_estimate=True is requested); None otherwise
     hypergrad_error_estimate: Optional[jnp.ndarray] = None
+    # matvecs charged to each instance where a solver runs instances in
+    # lockstep (pallas_cg: every matvec of its kernel block, frozen rows
+    # included); None otherwise
+    matvecs: Optional[jnp.ndarray] = None
 
 
 def _maybe_info(x, info: Optional[SolveInfo], return_info: bool):
@@ -814,7 +819,8 @@ MAX_DENSE_DIM = 512
 def solve_pallas_cg(matvec: Callable, b, *, init=None, tol: float = 1e-6,
                     maxiter: int = 1000, ridge: float = 0.0, precond=None,
                     return_info: bool = False, batch_ndim: int = 0,
-                    interpret: Optional[bool] = None, block_b="auto"):
+                    interpret: Optional[bool] = None, block_b="auto",
+                    tap=None):
     """Materialize per-instance operators and run the fused Pallas CG kernel.
 
     Dense small-system regime (d ≤ ``MAX_DENSE_DIM``) that dominates
@@ -831,6 +837,12 @@ def solve_pallas_cg(matvec: Callable, b, *, init=None, tol: float = 1e-6,
     ``info.residual`` is the true residual ``|b - A x|`` the kernel itself
     stopped on (it recomputes it and restarts CG while it is above
     ``tol``), so ``info.converged`` reports the kernel's own decision.
+    ``info.iterations`` is each system's own CG steps and ``info.matvecs``
+    the matvecs its kernel block ran, both counted by the kernel.
+
+    ``tap`` (a zero array of shape ``batch + (2,)``) reads the same two
+    counts for the backward solve: they are its cotangent when the caller
+    differentiates with respect to it (``batched_cg``'s ``tap``).
     """
     if init is not None:
         raise ValueError("pallas_cg always starts from zero; warm starts "
@@ -847,12 +859,15 @@ def solve_pallas_cg(matvec: Callable, b, *, init=None, tol: float = 1e-6,
             f"pallas_cg materializes dense systems; d={d} exceeds "
             f"MAX_DENSE_DIM={MAX_DENSE_DIM} — use a matrix-free solver")
     A, _ = materialize_batched(matvec, b, batch_ndim, view=view)
-    x, rn = batched_cg(A, view.b, tol=tol, maxiter=maxiter, block_b=block_b,
-                       interpret=interpret, return_residual=True)
+    if tap is not None:
+        tap = jnp.reshape(tap, (-1, 2))
+    x, rn, counts = batched_cg(A, view.b, tol=tol, maxiter=maxiter,
+                               block_b=block_b, interpret=interpret, tap=tap,
+                               return_info=True)
     if return_info:
         atol = jnp.maximum(tol * jnp.linalg.norm(view.b, axis=-1), 1e-30)
-        info = SolveInfo(iterations=jnp.full_like(rn, -1, dtype=jnp.int32),
-                         residual=rn, converged=rn <= atol)
+        info = SolveInfo(iterations=counts[:, 0], residual=rn,
+                         converged=rn <= atol, matvecs=counts[:, 1])
         if batch_ndim == 0:
             info = _squeeze_info(info)
         return view.to_tree(x), info

@@ -14,7 +14,10 @@ iteration is one fused step:
     iterate, and the while_loop exits as soon as the whole block converged,
   * convergence is judged on the true residual ``b - A x``: the recursive
     residual drifts from it by rounding, so it is recomputed once the
-    recursive one meets ``tol`` and CG restarts where it is still above.
+    recursive one meets ``tol`` and CG restarts where it is still above,
+  * the kernel counts its own work: each system's own CG steps, and the
+    matvecs its block ran, which every system of the block pays for
+    whether it was still iterating or frozen.
 
 Dense small-system regime: d ≤ 512.  The operator block is double-buffered
 in VMEM, so an (8, 512, 512) f32 block takes 16 MiB, which with the other
@@ -59,11 +62,11 @@ def _batched_cg_kernel(a_ref, b_ref, x_ref, rn_ref, *, tol: float,
     atol2 = jnp.maximum(tol * tol * b2, 1e-30)
 
     def cond(state):
-        _, _, _, rs, k = state
+        rs, k = state[3], state[4]
         return jnp.logical_and(k < maxiter, jnp.any(rs > atol2))
 
     def body(state):
-        x, r, p, rs, k = state
+        x, r, p, rs, k, steps, restarts = state
         active = rs > atol2                             # (bb,)
         ap = matvec(p)
         denom = jnp.sum(p * ap, axis=-1)
@@ -76,23 +79,35 @@ def _batched_cg_kernel(a_ref, b_ref, x_ref, rn_ref, *, tol: float,
         beta = jnp.where(rs == 0, 0.0, rs_new / jnp.where(rs == 0, 1.0, rs))
         p = jnp.where(active[:, None], r + beta[:, None] * p, p)
         rs = jnp.where(active, rs_new, rs)
-        return x, r, p, rs, k + 1
+        steps = steps + jnp.where(active, 1, 0)         # each row's own steps
+        return x, r, p, rs, k + 1, steps, restarts
 
     def replace_residual(state):
         # run CG until the recursive residuals meet tol, then replace them
         # with the true residuals b - A x and restart CG from x wherever
         # rounding left those above tol
-        x, _, _, _, k = lax.while_loop(cond, body, state)
+        x, _, _, _, k, steps, restarts = lax.while_loop(cond, body, state)
         r = b - matvec(x)
-        return x, r, r, jnp.sum(r * r, axis=-1), k
+        return x, r, r, jnp.sum(r * r, axis=-1), k, steps, restarts + 1
 
     x0 = jnp.zeros_like(b)                              # r = b - A·0 = b
-    x, _, _, rs, _ = lax.while_loop(
-        cond, replace_residual, (x0, b, b, b2, jnp.int32(0)))
+    steps0 = jnp.zeros(b2.shape, jnp.int32)
+    x, _, _, rs, k, steps, restarts = lax.while_loop(
+        cond, replace_residual,
+        (x0, b, b, b2, jnp.int32(0), steps0, jnp.int32(0)))
     x_ref[...] = x.astype(x_ref.dtype)
-    # the true residual norm each row stopped on, across the lane tile
-    rn_ref[...] = jnp.broadcast_to(jnp.sqrt(rs)[:, None],
-                                   rn_ref.shape).astype(rn_ref.dtype)
+    # lane 0: the true residual norm each row stopped on; lane 1: the row's
+    # own CG steps; lane 2: the matvecs its block ran (every CG step of the
+    # block plus one per true-residual recomputation), which every row of
+    # the block pays for
+    shape = rn_ref.shape
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    own = jnp.broadcast_to(steps[:, None], shape).astype(dtype)
+    charged = jnp.broadcast_to(k + restarts, shape).astype(dtype)
+    rn = jnp.broadcast_to(jnp.sqrt(rs)[:, None], shape)
+    rn_ref[...] = jnp.where(lane == 0, rn, jnp.where(
+        lane == 1, own, jnp.where(lane == 2, charged, 0))).astype(
+            rn_ref.dtype)
 
 
 LANES = 128     # TPU vector-lane width: the last dim of a VMEM tile
@@ -171,8 +186,11 @@ def pad_to_lanes(A, b, lanes: int = LANES):
 def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
                       block_b: int = SUBLANES, interpret: bool = False,
                       pad_lanes: bool = False):
-    """A: (B, d, d) SPD batch; b: (B, d).  Returns ``(x, rn)``: x (B, d)
-    with A x ≈ b, and the (B,) true residual norms the systems stopped on.
+    """A: (B, d, d) SPD batch; b: (B, d).  Returns ``(x, rn, counts)``:
+    x (B, d) with A x ≈ b, the (B,) true residual norms the systems stopped
+    on, and (B, 2) int32 counts: each system's own CG steps, and the
+    matvecs charged to it (those of its block: every step of the block's
+    loop, plus one per true-residual recomputation).
 
     Each system runs CG until its recursive residual meets ``tol``, then
     the kernel recomputes the true residual ``b - A x`` and restarts CG
@@ -188,9 +206,10 @@ def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
     """
     if pad_lanes:
         A, b, d0 = pad_to_lanes(A, b)
-        x, rn = batched_cg_pallas(A, b, tol=tol, maxiter=maxiter,
-                                  block_b=block_b, interpret=interpret)
-        return x[:, :d0], rn
+        x, rn, counts = batched_cg_pallas(A, b, tol=tol, maxiter=maxiter,
+                                          block_b=block_b,
+                                          interpret=interpret)
+        return x[:, :d0], rn, counts
     B, d, d2 = A.shape
     assert d == d2, (d, d2)
     assert b.shape == (B, d), (A.shape, b.shape)
@@ -225,4 +244,4 @@ def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
             transcendentals=0),
         interpret=interpret,
     )(A, b)
-    return x[:B], rn[:B, 0]
+    return x[:B], rn[:B, 0], rn[:B, 1:3].astype(jnp.int32)

@@ -11,6 +11,13 @@ kernel boundary — x = A⁻¹b is implicitly defined by Ax − b = 0, so
 
 which makes the op exactly as differentiable as a dense solve at the cost of
 one extra batched CG.
+
+Both solves count their own work on the device (``kernel.py``): each
+system's own CG steps and the matvecs charged to it.  The forward's counts
+are an output; the backward's reach the caller as the cotangent of
+``tap``, a zero (B, 2) operand that only a caller who wants them passes
+and differentiates against — no host callback, and with no tap the
+program is the one it would be without counters.
 """
 from __future__ import annotations
 
@@ -24,27 +31,30 @@ from repro.kernels.batched_cg.kernel import batched_cg_pallas
 from repro.kernels.batched_cg.ref import batched_cg_ref
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
-def _solve(A, b, tol, maxiter, block_b, interpret, pad_lanes):
-    """``(x, rn)``: the solutions and the true residual norms they stopped
-    on.  ``rn`` is a diagnostic: the custom VJP ignores its cotangent."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _solve(A, b, tap, tol, maxiter, block_b, interpret, pad_lanes):
+    """``(x, rn, counts)``: the solutions, the true residual norms they
+    stopped on and the (B, 2) int32 counts (own CG steps, matvecs
+    charged).  ``rn`` and ``counts`` are diagnostics: the custom VJP
+    ignores their cotangents.  ``tap`` (None, or a (B, 2) zero array) takes
+    the transposed solve's counts as its cotangent."""
     if interpret is None:      # no TPU: identical masked-CG reference path
         return batched_cg_ref(A, b, tol=tol, maxiter=maxiter)
     return batched_cg_pallas(A, b, tol=tol, maxiter=maxiter, block_b=block_b,
                              interpret=interpret, pad_lanes=pad_lanes)
 
 
-def _fwd(A, b, tol, maxiter, block_b, interpret, pad_lanes):
-    x, rn = _solve(A, b, tol, maxiter, block_b, interpret, pad_lanes)
-    return (x, rn), (A, x)
+def _fwd(A, b, tap, tol, maxiter, block_b, interpret, pad_lanes):
+    out = _solve(A, b, tap, tol, maxiter, block_b, interpret, pad_lanes)
+    return out, (A, out[0], tap)
 
 
 def _bwd(tol, maxiter, block_b, interpret, pad_lanes, res, g):
-    A, x = res
-    u, _ = _solve(A.transpose(0, 2, 1), g[0], tol, maxiter, block_b,
-                  interpret, pad_lanes)
+    A, x, tap = res
+    u, _, counts = _solve(A.transpose(0, 2, 1), g[0], None, tol, maxiter,
+                          block_b, interpret, pad_lanes)
     dA = -u[:, :, None] * x[:, None, :]
-    return dA, u
+    return dA, u, None if tap is None else counts.astype(tap.dtype)
 
 
 _solve.defvjp(_fwd, _bwd)
@@ -52,7 +62,7 @@ _solve.defvjp(_fwd, _bwd)
 
 def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
                block_b=8, interpret: Optional[bool] = None,
-               pad_lanes: bool = False, return_residual: bool = False):
+               pad_lanes: bool = False, tap=None, return_info: bool = False):
     """Solve the batch of SPD systems A[i] x[i] = b[i] in one fused kernel.
 
     Args:
@@ -77,8 +87,15 @@ def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
         width (identity pad, exact — see ``kernel.pad_to_lanes``) before
         the Pallas call; ignored on the reference path, which has no
         tiling constraint.
-      return_residual: also return the per-instance true residual norms
-        ``|b - A x|`` that convergence was judged on (not differentiated).
+      tap: None, or a (B, 2) floating zero array (one row for operator
+        input with ``batch_ndim == 0``); differentiating with respect to
+        it reads the backward (transposed) solve's counts, own CG steps
+        and matvecs charged, as its cotangent.  The solution and its
+        derivatives do not depend on it.
+      return_info: also return the per-instance true residual norms
+        ``|b - A x|`` that convergence was judged on and the (B, 2) int32
+        counts of the forward solve (own CG steps, matvecs charged), as
+        ``(x, rn, counts)``; neither is differentiated.
 
     Differentiable in A and b via the implicit-diff custom VJP (operator
     input: in b, through the materialized matrix).
@@ -91,12 +108,14 @@ def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
         dense = A.materialize()
         if A.batch_ndim == 0:
             dense = dense[None]
-        x, rn = batched_cg(dense, view.b, tol=tol, maxiter=maxiter,
-                           block_b=block_b, interpret=interpret,
-                           pad_lanes=pad_lanes, return_residual=True)
+        x, rn, counts = batched_cg(dense, view.b, tol=tol, maxiter=maxiter,
+                                   block_b=block_b, interpret=interpret,
+                                   pad_lanes=pad_lanes, tap=tap,
+                                   return_info=True)
         if A.batch_ndim == 0:
-            rn = rn[0]
-        return (view.to_tree(x), rn) if return_residual else view.to_tree(x)
+            rn, counts = rn[0], counts[0]
+        x = view.to_tree(x)
+        return (x, rn, counts) if return_info else x
     B, d, _ = A.shape
     if maxiter is None:
         maxiter = d
@@ -110,6 +129,6 @@ def batched_cg(A, b, *, tol: float = 1e-6, maxiter: Optional[int] = None,
         interpret = None   # sentinel: ref path (see _solve)
     elif interpret is None:
         interpret = False
-    x, rn = _solve(A, b, float(tol), int(maxiter), int(block_b), interpret,
-                   bool(pad_lanes))
-    return (x, rn) if return_residual else x
+    x, rn, counts = _solve(A, b, tap, float(tol), int(maxiter), int(block_b),
+                           interpret, bool(pad_lanes))
+    return (x, rn, counts) if return_info else x

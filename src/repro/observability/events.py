@@ -222,7 +222,7 @@ def _bridge_metrics(ev: SolveEvent) -> None:
     its = ev.values.get("iterations")
     if its is not None and ev.kind in ("solve", "converged"):
         arr = np.asarray(its, dtype=np.float64).ravel()
-        arr = arr[arr >= 0]          # -1 marks untracked (pallas_cg)
+        arr = arr[arr >= 0]          # negative: uncounted (custom solvers)
         if arr.size:
             reg.histogram("repro_solve_iterations",
                           help="per-instance solver iteration counts",

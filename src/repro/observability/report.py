@@ -55,7 +55,7 @@ def _iter_histogram(counts: List[float]) -> Dict[str, int]:
     """Power-of-two bucket histogram of iteration counts."""
     hist: Dict[str, int] = {}
     for c in counts:
-        if c < 0:                    # -1 marks untracked (pallas_cg)
+        if c < 0:                    # negative: uncounted (custom solvers)
             continue
         lo = 1
         while lo * 2 <= max(c, 1):

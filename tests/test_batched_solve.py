@@ -224,9 +224,9 @@ class TestPallasBatchedCG:
         As = _spd_batch(rng, B, d).astype(jnp.float32)
         bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
                                jnp.float32)
-        out, _ = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
+        out, _, _ = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
                                    block_b=block_b, interpret=True)
-        ref, _ = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
+        ref, _, _ = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
@@ -235,7 +235,7 @@ class TestPallasBatchedCG:
         As = _spd_batch(rng, B, d).astype(jnp.float32)
         bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
                                jnp.float32)
-        x, _ = batched_cg_ref(As, bs, tol=1e-8, maxiter=4 * d)
+        x, _, _ = batched_cg_ref(As, bs, tol=1e-8, maxiter=4 * d)
         res = jnp.linalg.norm(jnp.einsum("bij,bj->bi", As, x) - bs, axis=-1)
         rel = res / jnp.linalg.norm(bs, axis=-1)
         assert float(jnp.max(rel)) < 1e-5
@@ -334,10 +334,10 @@ class TestLanePadding:
         As = _spd_batch(rng, B, d).astype(jnp.float32)
         bs = jax.random.normal(jax.random.fold_in(rng, 1), (B, d),
                                jnp.float32)
-        out, _ = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
+        out, _, _ = batched_cg_pallas(As, bs, tol=1e-6, maxiter=2 * d,
                                    block_b=block_b, interpret=True,
                                    pad_lanes=True)
-        ref, _ = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
+        ref, _, _ = batched_cg_ref(As, bs, tol=1e-6, maxiter=2 * d)
         assert out.shape == (B, d)      # solution sliced back to d
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)

@@ -179,3 +179,63 @@ def test_batch_sharded_hypergradient_compiles_on_four_chips(topo,
             for shape, s in zip(((B,), (B, m, d), (B, m)), specs)]
     compiled = jax.jit(grad).lower(*args).compile()
     assert compiled.output_shardings.spec == P("data")
+
+
+def _host_transfers(text: str) -> int:
+    """Host callbacks in a compiled TPU program: on the TPU they lower to
+    send/recv pairs marked as host transfers."""
+    return text.count("is_host_transfer=true")
+
+
+def test_counting_kernel_compiles_at_the_ridge_probe_size(one_chip):
+    """1,000 systems of d=512 at the 8-row tile the rule picks: the kernel
+    that writes its step and matvec counts into the residual tile's lanes
+    still fits the 48 MiB VMEM limit."""
+    assert block_rows(1000, 512) == (8, 1000)
+    text = _compiled_text(
+        lambda A, b: batched_cg(A, b, tol=1e-6, maxiter=1000, block_b=8,
+                                return_info=True),
+        _spec((1000, 512, 512), one_chip), _spec((1000, 512), one_chip))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def _counted_hypergradient(K, d):
+    """Hypergradients over per-class ridge regularisations through the
+    dense SPD solve, with the forward's counts (``return_info``) and the
+    backward's (the tap's cotangent) as outputs."""
+    def step(theta, G, C):
+        def val_loss(theta, tap):
+            A = G[None] + theta[:, None, None] * jnp.eye(d, dtype=G.dtype)
+            x, info = ls.solve(DenseOperator(A, positive_definite=True), C,
+                               method="auto", tol=1e-6, maxiter=1000,
+                               return_info=True, tap=tap)
+            counts = jnp.stack([info.iterations, info.matvecs], -1)
+            return jnp.sum(x * x), counts
+
+        tap = jnp.zeros((K, 2), jnp.float32)
+        (_, fwd), (grad, bwd) = jax.value_and_grad(
+            val_loss, argnums=(0, 1), has_aux=True)(theta, tap)
+        return grad, fwd, bwd
+
+    return step
+
+
+def test_counted_hypergradient_has_two_kernels_and_no_host_callback(
+        one_chip):
+    """At the ridge probe's size (1,000 classes, d=512) the counted step
+    holds the forward and the transposed kernel and nothing that talks to
+    the host; the same step with observability on does, so the check can
+    fail."""
+    from repro import observability as obs
+    K, d = 1000, 512
+    text = _compiled_text(_counted_hypergradient(K, d),
+                          _spec((K,), one_chip), _spec((d, d), one_chip),
+                          _spec((K, d), one_chip))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert _host_transfers(text) == 0
+    with obs.observe(enabled=True):
+        observed = _compiled_text(_counted_hypergradient(8, 128),
+                                  _spec((8,), one_chip),
+                                  _spec((128, 128), one_chip),
+                                  _spec((8, 128), one_chip))
+    assert _host_transfers(observed) > 0
