@@ -19,10 +19,21 @@ iteration is one fused step:
     matvecs its block ran, which every system of the block pays for
     whether it was still iterating or frozen.
 
+A block loops until its slowest system meets tol, so which systems share a
+block decides how many matvecs go to frozen rows.  By default block i holds
+rows ``[block_b·i, block_b·(i+1))`` in the caller's order, fetched by the
+``BlockSpec`` pipeline.  Given an ``order`` (a permutation of the batch),
+block i holds rows ``order[block_b·i + j]`` instead: the operator stays in
+HBM and the kernel copies each row's (d, d) matrix into one of two
+block-sized VMEM slots, the next block's rows while this one iterates.
+Every row's iterates depend on its own system alone, so the order changes
+no solution, residual or own step count, only the matvecs each block runs.
+
 Dense small-system regime: d ≤ 512.  The operator block is double-buffered
-in VMEM, so an (8, 512, 512) f32 block takes 16 MiB, which with the other
-buffers is more than the 16 MiB scoped-VMEM default of a v5e: that is why the kernel asks for
-``VMEM_LIMIT_BYTES`` and ``block_rows`` sizes the block against
+in VMEM (two pipeline buffers, or the ordered kernel's two slots), so an
+(8, 512, 512) f32 block takes 16 MiB, which with the other buffers is more
+than the 16 MiB scoped-VMEM default of a v5e: that is why the kernel asks
+for ``VMEM_LIMIT_BYTES`` and ``block_rows`` sizes the block against
 ``BLOCK_BUDGET_BYTES``.  For larger or matrix-free systems use the masked
 solvers in ``repro.core.linear_solve``.
 
@@ -110,6 +121,37 @@ def _batched_cg_kernel(a_ref, b_ref, x_ref, rn_ref, *, tol: float,
             rn_ref.dtype)
 
 
+def _ordered_cg_kernel(order_ref, a_hbm, b_ref, x_ref, rn_ref, a_buf, sems,
+                       *, tol: float, maxiter: int):
+    # block i solves rows order[bb*i : bb*(i+1)] of the batch: each row's
+    # operator is copied from HBM into one of two VMEM slots, and the next
+    # block's copies run while this block iterates.  b arrives, and x and
+    # rn leave, already in that order (the caller permutes them)
+    i, n = pl.program_id(0), pl.num_programs(0)
+    bb = a_buf.shape[1]
+    slot = i % 2
+
+    def rows(blk, slot):
+        return [pltpu.make_async_copy(a_hbm.at[order_ref[blk * bb + j]],
+                                      a_buf.at[slot, j], sems.at[slot, j])
+                for j in range(bb)]
+
+    @pl.when(i == 0)
+    def _():
+        for copy in rows(0, 0):
+            copy.start()
+
+    @pl.when(i + 1 < n)
+    def _():
+        for copy in rows(i + 1, 1 - slot):
+            copy.start()
+
+    for copy in rows(i, slot):
+        copy.wait()
+    _batched_cg_kernel(a_buf.at[slot], b_ref, x_ref, rn_ref, tol=tol,
+                       maxiter=maxiter)
+
+
 LANES = 128     # TPU vector-lane width: the last dim of a VMEM tile
 SUBLANES = 8    # sublane height: the second-to-last dim of a VMEM tile
 #: scoped VMEM the compiled kernel asks for (a v5e core has 128 MiB)
@@ -185,12 +227,12 @@ def pad_to_lanes(A, b, lanes: int = LANES):
 
 def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
                       block_b: int = SUBLANES, interpret: bool = False,
-                      pad_lanes: bool = False):
+                      pad_lanes: bool = False, order=None):
     """A: (B, d, d) SPD batch; b: (B, d).  Returns ``(x, rn, counts)``:
     x (B, d) with A x ≈ b, the (B,) true residual norms the systems stopped
     on, and (B, 2) int32 counts: each system's own CG steps, and the
-    matvecs charged to it (those of its block: every step of the block's
-    loop, plus one per true-residual recomputation).
+    matvecs charged to it (those of the block it was solved in: every step
+    of the block's loop, plus one per true-residual recomputation).
 
     Each system runs CG until its recursive residual meets ``tol``, then
     the kernel recomputes the true residual ``b - A x`` and restarts CG
@@ -203,12 +245,21 @@ def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
     is not a multiple of the 128-lane VMEM tile width into the next lane
     multiple (identity pad — see ``pad_to_lanes``) and slices the solution
     back.
+
+    ``order`` (None, or a (B,) integer permutation of the batch) fills the
+    blocks in that order: block i solves systems ``order[block_b·i + j]``,
+    whose operators the kernel copies row by row from HBM (scalar-prefetched
+    indices, two VMEM slots), while ``b`` is gathered and the results
+    scattered back in XLA, so they come back in the caller's order.  The
+    solutions, residual norms and own steps are those of the unordered
+    solve; only the matvecs charged follow the new blocks.  A batch that
+    is a single block ignores the order.
     """
     if pad_lanes:
         A, b, d0 = pad_to_lanes(A, b)
         x, rn, counts = batched_cg_pallas(A, b, tol=tol, maxiter=maxiter,
                                           block_b=block_b,
-                                          interpret=interpret)
+                                          interpret=interpret, order=order)
         return x[:, :d0], rn, counts
     B, d, d2 = A.shape
     assert d == d2, (d, d2)
@@ -226,22 +277,50 @@ def batched_cg_pallas(A, b, *, tol: float = 1e-6, maxiter: int = 64,
         b = jnp.pad(b, ((0, Bp - B), (0, 0)))
     rn_dtype = jnp.promote_types(jnp.result_type(A.dtype, b.dtype),
                                  jnp.float32)
-    kernel = functools.partial(_batched_cg_kernel, tol=tol, maxiter=maxiter)
+    out_shape = [jax.ShapeDtypeStruct((Bp, d), b.dtype),
+                 jax.ShapeDtypeStruct((Bp, LANES), rn_dtype)]
+    cost_estimate = pl.CostEstimate(   # whole-call totals, worst case
+        flops=2 * maxiter * Bp * d * d,
+        bytes_accessed=itemsize * (Bp * d * d + 2 * Bp * d),
+        transcendentals=0)
+    kw = dict(tol=tol, maxiter=maxiter)
+    if order is None or Bp == block_b:    # one block: no order to keep
+        x, rn = pl.pallas_call(
+            functools.partial(_batched_cg_kernel, **kw),
+            grid=(Bp // block_b,),
+            in_specs=[pl.BlockSpec((block_b, d, d), lambda i: (i, 0, 0)),
+                      pl.BlockSpec((block_b, d), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((block_b, d), lambda i: (i, 0)),
+                       pl.BlockSpec((block_b, LANES), lambda i: (i, 0))],
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            cost_estimate=cost_estimate, interpret=interpret,
+        )(A, b)
+        return x[:B], rn[:B, 0], rn[:B, 1:3].astype(jnp.int32)
+    # padded rows keep their place at the end; each block's next rows are
+    # fetched while it iterates, so the grid runs in sequence
+    order = jnp.concatenate([jnp.asarray(order, jnp.int32),
+                             jnp.arange(B, Bp, dtype=jnp.int32)])
     x, rn = pl.pallas_call(
-        kernel,
-        grid=(Bp // block_b,),
-        in_specs=[pl.BlockSpec((block_b, d, d), lambda i: (i, 0, 0)),
-                  pl.BlockSpec((block_b, d), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-                   pl.BlockSpec((block_b, LANES), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((Bp, d), b.dtype),
-                   jax.ShapeDtypeStruct((Bp, LANES), rn_dtype)],
+        functools.partial(_ordered_cg_kernel, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Bp // block_b,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((block_b, d), lambda i, o: (i, 0))],
+            out_specs=[pl.BlockSpec((block_b, d), lambda i, o: (i, 0)),
+                       pl.BlockSpec((block_b, LANES), lambda i, o: (i, 0))],
+            scratch_shapes=[pltpu.VMEM((2, block_b, d, d), A.dtype),
+                            pltpu.SemaphoreType.DMA((2, block_b))]),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        cost_estimate=pl.CostEstimate(   # whole-call totals, worst case
-            flops=2 * maxiter * Bp * d * d,
-            bytes_accessed=itemsize * (Bp * d * d + 2 * Bp * d),
-            transcendentals=0),
-        interpret=interpret,
-    )(A, b)
-    return x[:B], rn[:B, 0], rn[:B, 1:3].astype(jnp.int32)
+        cost_estimate=cost_estimate, interpret=interpret,
+    )(order, A, b[order])
+    # back to the caller's order: gather by the inverse permutation
+    where = jnp.zeros_like(order).at[order].set(
+        jnp.arange(Bp, dtype=jnp.int32))[:B]
+    rn = rn[where, :3]
+    return x[where], rn[:, 0], rn[:, 1:3].astype(jnp.int32)

@@ -243,3 +243,173 @@ def test_solve_event_carries_the_kernel_counts(rng):
     its = np.asarray(events[0].values["iterations"])
     np.testing.assert_array_equal(its, np.asarray(info.iterations))
     assert np.all(its > 0)
+
+
+# -- the ordered tiling: blocks filled in a given order of the systems ------
+
+def _ridge_batch(key, B=64, d=32):
+    """``G + θI`` with θ a log grid dealt to the systems in a shuffled
+    order: each 8-row block mixes fast (high θ) and slow (low θ) systems,
+    as the benchmark's per-class ridge step does."""
+    M = jax.random.normal(key, (4 * d, d), jnp.float32) / 8
+    theta = jnp.logspace(-3.0, 1.0, B, dtype=jnp.float32)[
+        jax.random.permutation(jax.random.fold_in(key, 2), B)]
+    A = M.T @ M + theta[:, None, None] * jnp.eye(d, dtype=jnp.float32)
+    return A, _rhs(key, B, d)
+
+
+def _orders(counts):
+    own = np.asarray(counts[:, 0])
+    B = own.shape[0]
+    return {"own_steps": np.argsort(own, kind="stable"),
+            "reversed": np.arange(B)[::-1],
+            "shuffled": np.asarray(jax.random.permutation(
+                jax.random.PRNGKey(7), B))}
+
+
+@pytest.mark.parametrize("pad_lanes", [False, True])
+@pytest.mark.parametrize("kind", ["own_steps", "reversed", "shuffled"])
+def test_ordered_solve_is_bitwise_the_unordered_one(rng, kind, pad_lanes):
+    """Each row's iterates depend on its own system alone, so solving the
+    batch in another block order changes no solution, residual norm or
+    own step count, and all come back in the caller's order."""
+    A, b = _ridge_batch(rng)
+    kw = dict(tol=1e-6, maxiter=1000, interpret=True, pad_lanes=pad_lanes)
+    x, rn, counts = batched_cg_pallas(A, b, **kw)
+    order = _orders(counts)[kind]
+    xo, rno, co = batched_cg_pallas(A, b, order=jnp.asarray(order), **kw)
+    np.testing.assert_array_equal(np.asarray(xo), np.asarray(x))
+    np.testing.assert_array_equal(np.asarray(rno), np.asarray(rn))
+    np.testing.assert_array_equal(np.asarray(co[:, 0]),
+                                  np.asarray(counts[:, 0]))
+    # each row is charged the matvecs of the block it was solved in
+    blocks = np.asarray(co[:, 1])[order].reshape(-1, 8)
+    assert np.all(blocks == blocks[:, :1])
+
+
+def test_ordered_solve_of_a_padded_batch(rng, monkeypatch):
+    """A batch the tile rule pads (12 rows in blocks of 8): the identity
+    rows keep their place at the end, and the 12 real rows come back
+    bitwise as the unordered solve gives them."""
+    d = 8
+    monkeypatch.setattr(kernel, "BLOCK_BUDGET_BYTES", 8 * d * d * 8)
+    assert kernel.block_rows(12, d) == (8, 16)
+    A, b = _spd(rng, 12, d, 30.0), _rhs(rng, 12, d)
+    x, rn, counts = _kernel(A, b)
+    xo, rno, co = batched_cg_pallas(A, b, tol=1e-6, maxiter=1000,
+                                    interpret=True,
+                                    order=jnp.arange(12)[::-1])
+    np.testing.assert_array_equal(np.asarray(xo), np.asarray(x))
+    np.testing.assert_array_equal(np.asarray(rno), np.asarray(rn))
+    np.testing.assert_array_equal(np.asarray(co[:, 0]),
+                                  np.asarray(counts[:, 0]))
+
+
+def test_ordering_by_own_steps_charges_fewer_matvecs(rng):
+    """Blocks of systems that finish at about the same step spend fewer
+    matvecs on frozen rows: the charged total falls below the unordered
+    one, while the systems' own steps stay the same."""
+    A, b = _ridge_batch(rng)
+    _, _, counts = _kernel(A, b)
+    order = _orders(counts)["own_steps"]
+    _, _, co = batched_cg_pallas(A, b, tol=1e-6, maxiter=1000,
+                                 interpret=True, order=jnp.asarray(order))
+    co, counts = np.asarray(co), np.asarray(counts)
+    assert co[:, 1].sum() < counts[:, 1].sum()
+    assert co[:, 1].sum() >= co[:, 0].sum()
+
+
+def _ridge_hypergrad(A, C):
+    """Hypergradients over θ of a validation loss through
+    ``linear_solve.solve(method="pallas_cg")``, with the backward's counts
+    read through the tap."""
+    B, d = A.shape[:2]
+
+    def val_loss(theta, tap):
+        Ai = A + theta[:, None, None] * jnp.eye(d, dtype=A.dtype)
+        x = ls.solve(DenseOperator(Ai, positive_definite=True), C,
+                     method="pallas_cg", tol=1e-6, maxiter=1000,
+                     interpret=True, tap=tap)
+        return jnp.sum((x - 1.0) ** 2)
+
+    return jax.grad(val_loss, argnums=(0, 1))(
+        jnp.zeros((B,), A.dtype), jnp.zeros((B, 2), jnp.float32))
+
+
+def test_ordered_backward_hypergradients_are_bitwise_the_unordered(
+        rng, monkeypatch):
+    """The backward tiles its blocks by the forward's own steps: the
+    hypergradients and the backward's own steps are the bits the unordered
+    backward gives, and it is charged fewer matvecs."""
+    from repro.kernels.batched_cg import ops
+    A, C = _ridge_batch(rng)
+    grad, tap = _ridge_hypergrad(A, C)
+    unordered = ops.batched_cg_pallas
+
+    def without_order(*args, order=None, **kw):
+        return unordered(*args, **kw)
+
+    monkeypatch.setattr(ops, "batched_cg_pallas", without_order)
+    grad0, tap0 = _ridge_hypergrad(A, C)
+    np.testing.assert_array_equal(np.asarray(grad), np.asarray(grad0))
+    np.testing.assert_array_equal(np.asarray(tap[:, 0]),
+                                  np.asarray(tap0[:, 0]))
+    assert float(tap[:, 1].sum()) < float(tap0[:, 1].sum())
+
+
+def test_tap_cotangent_is_the_ordered_transposed_solve_counts(rng):
+    """On a multi-block batch the tap reads the counts of the transposed
+    solve tiled by the forward's own steps: the same as solving ``Aᵀ u =
+    ∂L/∂x`` directly in that order."""
+    A, b = _ridge_batch(rng)
+    A = A + 0.01 * jnp.triu(A, 1)        # Aᵀ is another batch of systems
+    B = A.shape[0]
+    kw = dict(tol=1e-6, maxiter=1000, interpret=True)
+
+    def loss(A, tap):
+        return jnp.sum(batched_cg(A, b, tap=tap, **kw) ** 2)
+
+    _, dtap = jax.grad(loss, argnums=(0, 1))(A, jnp.zeros((B, 2), jnp.float32))
+    x, _, fwd = batched_cg(A, b, return_info=True, **kw)
+    order = jnp.argsort(fwd[:, 0], stable=True)
+    _, _, direct = batched_cg_pallas(A.transpose(0, 2, 1), 2 * x, order=order,
+                                     **kw)
+    np.testing.assert_array_equal(np.asarray(dtap), np.asarray(direct))
+
+
+def test_single_block_batch_ignores_the_order(rng):
+    """A batch that is one block cannot be tiled otherwise: the order is
+    not used (even one that is no permutation), and no row is gathered."""
+    B, d = 8, 32
+    A, b = _spd(rng, B, d, 50.0), _rhs(rng, B, d)
+    kw = dict(tol=1e-6, maxiter=500, interpret=True)
+    out = batched_cg_pallas(A, b, **kw)
+    bad = jnp.zeros((B,), jnp.int32)
+    ordered = batched_cg_pallas(A, b, order=bad, **kw)
+    for got, want in zip(ordered, out):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    jaxpr = str(jax.make_jaxpr(
+        lambda A, b, o: batched_cg_pallas(A, b, order=o, **kw))(A, b, bad))
+    assert "gather" not in jaxpr
+
+
+def test_vmapped_ordered_solve_is_each_instance_alone(rng):
+    """``jax.vmap`` over ordered multi-block solves, each instance with its
+    own order (as the backward of a vmapped hypergradient gets): every
+    instance comes back as its ordered solve alone gives it."""
+    V, B, d = 3, 24, 16
+    A = jnp.stack([_ridge_batch(jax.random.fold_in(rng, i), B, d)[0]
+                   for i in range(V)])
+    b = jax.random.normal(rng, (V, B, d), jnp.float32)
+    orders = jnp.stack([jax.random.permutation(jax.random.fold_in(rng, i), B)
+                        for i in range(V)])
+
+    def solve(A, b, order):
+        return batched_cg_pallas(A, b, tol=1e-6, maxiter=1000,
+                                 interpret=True, order=order)
+
+    batched = jax.vmap(solve)(A, b, orders)
+    for i in range(V):
+        for got, want in zip(batched, solve(A[i], b[i], orders[i])):
+            np.testing.assert_array_equal(np.asarray(got[i]),
+                                          np.asarray(want))

@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import DenseOperator, ImplicitDiffSpec, custom_root
 from repro.core import implicit_diff
 from repro.core import linear_solve as ls
-from repro.kernels.batched_cg.kernel import block_rows
+from repro.kernels.batched_cg.kernel import batched_cg_pallas, block_rows
 from repro.kernels.batched_cg.ops import batched_cg
 
 
@@ -239,3 +239,32 @@ def test_counted_hypergradient_has_two_kernels_and_no_host_callback(
                                   _spec((128, 128), one_chip),
                                   _spec((8, 128), one_chip))
     assert _host_transfers(observed) > 0
+
+
+def _kernel_operands(lowered_text: str) -> list:
+    """The operand types of each ``tpu_custom_call`` in a lowered program."""
+    return [line.rsplit(" : (", 1)[1].split(") -> ")[0]
+            for line in lowered_text.splitlines()
+            if "@tpu_custom_call(" in line]
+
+
+def test_ordered_backward_compiles_at_the_ridge_probe_size(one_chip):
+    """The transposed solve tiles its blocks by the forward's own steps:
+    at 1,000 systems of d=512 the ordered kernel, which copies each row's
+    operator from HBM into one of two 8-row VMEM slots, compiles within
+    the 48 MiB VMEM limit, and in the counted hypergradient the backward
+    kernel is the one that takes the order (the scalar-prefetched int32
+    operand) while the forward's takes none."""
+    K, d = 1000, 512
+    text = _compiled_text(
+        lambda A, b, o: batched_cg_pallas(A, b, tol=1e-6, maxiter=1000,
+                                          order=o),
+        _spec((K, d, d), one_chip), _spec((K, d), one_chip),
+        _spec((K,), one_chip, jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    lowered = jax.jit(_counted_hypergradient(K, d)).lower(
+        _spec((K,), one_chip), _spec((d, d), one_chip),
+        _spec((K, d), one_chip)).as_text()
+    systems = f"tensor<{K}x{d}x{d}xf32>, tensor<{K}x{d}xf32>"
+    assert _kernel_operands(lowered) == [systems,
+                                         f"tensor<{K}xi32>, {systems}"]
