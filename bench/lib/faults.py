@@ -1,21 +1,24 @@
 """Faults planted under a cell's timed path, which its comparison must catch.
 
-Each fault is planted with a ``setattr(obj, name, value)`` the caller
-gives (pytest's ``monkeypatch.setattr`` in the tests; the plain builtin in
-``bench/readings.py --fault``, whose process ends after the reading):
-
-* ``altered``: one number of the produced solution (of the last system)
-  changed where the solve produces it;
-* ``half_batch``: half the batch left out (its solutions zeroed);
-* ``state_unchanged``: the step returns the state it was given, its step
-  index: every step repeats the first.
+Each entry's faults live in ``bench/faults/<entry>.py``, found by name
+like every other per-cell file: its ``FAULTS`` maps a fault's name to a
+planter, ``planter(setattr)``.  A fault is planted with a
+``setattr(obj, name, value)`` the caller gives (pytest's
+``monkeypatch.setattr`` in the tests; the plain builtin in
+``bench/readings.py --fault``, whose process ends after the reading).
+This module holds what the entries' tables share: wrapping the solution
+where ``linear_solve`` produces it, and two ways of changing it.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from bench.lib import harness
 
-def _wrap_solution(setattr, change):
+
+def wrap_solution(setattr, change):
+    """``change`` applied to every solution ``linear_solve.solve`` and
+    ``route_solve`` produce."""
     from repro.core import linear_solve as ls
     for name in ("solve", "route_solve"):
         real = getattr(ls, name)
@@ -28,35 +31,27 @@ def _wrap_solution(setattr, change):
         setattr(ls, name, wrapped)
 
 
-def _altered(x):
-    # the last system's: every comparison's sample holds it
+def altered(x):
+    """One number changed: the last system's, which every comparison's
+    sample holds."""
     return x.at[(-1,) * x.ndim].multiply(1.5)
 
 
-def _half(x):
+def half(x):
+    """Half the batch left out: its solutions zeroed."""
     keep = jnp.arange(x.shape[0]) < x.shape[0] // 2
     return jnp.where(keep.reshape((-1,) + (1,) * (x.ndim - 1)), x, 0.0)
 
 
-def _stale_spd(setattr):
-    from bench.entries import spd_hypergrad
-    real = spd_hypergrad.make_step
-
-    def stale(config):
-        step = real(config)
-        return lambda k, *a: (k, *step(k, *a)[1:])
-    setattr(spd_hypergrad, "make_step", stale)
+def table(entry: str, dirs=(harness.BENCH_DIR,)) -> dict:
+    """The entry's ``FAULTS``: fault name -> planter(setattr)."""
+    return harness.load_module("faults", entry, dirs).FAULTS
 
 
-#: entry -> fault -> planter(setattr)
-FAULTS = {
-    "spd_hypergrad": {
-        "altered": lambda s: _wrap_solution(s, _altered),
-        "half_batch": lambda s: _wrap_solution(s, _half),
-        "state_unchanged": _stale_spd,
-    },
-}
-
-
-def plant(entry: str, fault: str, setattr=setattr) -> None:
-    FAULTS[entry][fault](setattr)
+def plant(entry: str, fault: str, setattr=setattr,
+          dirs=(harness.BENCH_DIR,)) -> None:
+    faults = table(entry, dirs)
+    if fault not in faults:
+        raise ValueError(f"entry {entry!r} has no fault {fault!r}; "
+                         f"it has {sorted(faults)}")
+    faults[fault](setattr)

@@ -1,6 +1,11 @@
 """The benchmark's own tests: rehearsals on the CPU at tiny sizes.
 
     JAX_PLATFORMS=cpu python -m pytest bench/tests -q
+
+The sizes a rehearsal runs at are files found by name, like every other
+per-cell file: ``sizes/configs/<config>.json`` and
+``sizes/traffic/<traffic>.json`` beside this file, each with a ``tiny``
+and a ``control`` set of the keys it changes.
 """
 import json
 import os
@@ -13,46 +18,49 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import pytest  # noqa: E402
 
-#: per configuration and traffic mix, the keys a tiny rehearsal changes
-TINY = {
-    "configs": {
-        "resnet18_in1k_probe": {"classes": 8, "dim": 256,
-                                "train_rows": 4000, "val_rows": 1000},
-    },
-    "traffic": {},
-}
+from bench.lib import harness  # noqa: E402
+
+#: a cell's files whose sizes a rehearsal changes: kind -> workload key
+SIZED = {"configs": "config", "traffic": "traffic"}
 
 
 def bench_file():
     return json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
-#: the sizes at which the control's rounding shows on the CPU: the full
-#: width, rows enough that the Gram matrices' sums carry it, and theta
-#: scaled with the rows, so the systems keep the configuration's condition
-CONTROL = {"configs": {"resnet18_in1k_probe": {
-    "classes": 8, "dim": 512, "train_rows": 64000, "val_rows": 4000,
-    "theta_range": [500.0, 50000.0]}}}
-
-
 @pytest.fixture
 def tiny(tmp_path):
     """A directory of tiny copies of every configuration and traffic mix
-    that ``TINY`` shrinks, searched before ``bench/``."""
-    return write_sizes(tmp_path, TINY)
+    a cell names, searched before ``bench/``."""
+    return write_sizes(tmp_path, "tiny")
 
 
 @pytest.fixture
 def control_sized(tmp_path):
-    """As ``tiny``, at the sizes of ``CONTROL``."""
-    return write_sizes(tmp_path, CONTROL)
+    """As ``tiny``, at the sizes at which the control's rounding shows on
+    the CPU."""
+    return write_sizes(tmp_path, "control")
 
 
-def write_sizes(tmp_path, sizes):
-    for kind, entries in sizes.items():
-        (tmp_path / kind).mkdir()
-        for name, changes in entries.items():
-            src = ROOT / "bench" / kind / f"{name}.json"
-            data = dict(json.loads(src.read_text()), **changes)
-            (tmp_path / kind / f"{name}.json").write_text(json.dumps(data))
-    return tmp_path
+def sizes(kind, name, dirs=(harness.BENCH_DIR,)):
+    """The sizes file of configuration or traffic mix ``name``, from
+    ``tests/sizes/`` under the first of ``dirs`` that holds it."""
+    return harness.load_json(kind, name, [pathlib.Path(d) / "tests" / "sizes"
+                                          for d in dirs])
+
+
+def write_sizes(out, which, bench=None, dirs=()):
+    """Copies of every configuration and traffic mix that a cell of
+    ``bench`` (``BENCHMARK.json`` by default) names, with the ``which``
+    sizes of their sizes files, written to ``out/<kind>/``.  ``dirs`` are
+    searched before ``bench/``, for the files and their sizes alike.  A
+    file with no sizes raises: no cell is rehearsed at its full size."""
+    dirs = (*dirs, harness.BENCH_DIR)
+    for w in (bench or bench_file())["workloads"]:
+        for kind, key in SIZED.items():
+            name = w[key]
+            data = dict(harness.load_json(kind, name, dirs),
+                        **sizes(kind, name, dirs)[which])
+            (out / kind).mkdir(parents=True, exist_ok=True)
+            (out / kind / f"{name}.json").write_text(json.dumps(data))
+    return out
